@@ -9,15 +9,12 @@ wall time (CPU emulation — directional only), and HLO collective counts.
 
 from __future__ import annotations
 
-import os
 import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, cpu_child_env
 
 CODE = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, time, re
 from repro.configs import get_config
 from repro.models import build_model
@@ -63,10 +60,7 @@ for mode, bucket in (("auto", False), ("composed", False),
 def run() -> Table:
     t = Table("bench_e2e: conventional vs composed system (paper §5)",
               ["system", "loss@8", "ms/step (CPU emu)", "HLO collectives"])
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "src")
-    proc = subprocess.run([sys.executable, "-c", CODE], env=env,
+    proc = subprocess.run([sys.executable, "-c", CODE], env=cpu_child_env(),
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
         t.add("(subprocess failed)", proc.stderr[-300:], "", "")

@@ -23,13 +23,10 @@ Feeds the ``overlap`` block of ``BENCH_plan.json``.
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
-from benchmarks.common import Table
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from benchmarks.common import Table, cpu_child_env
 
 _SCRIPT = r"""
 import json, time
@@ -161,10 +158,7 @@ def overlap_metrics(smoke: bool = True, depth: int = 4) -> dict:
     pass timings and predicted-vs-measured phase bytes.  Raises on
     subprocess failure — ``run.py`` turns that into a loud nonzero exit
     rather than writing a partial BENCH_plan.json."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = (os.path.join(REPO, "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
+    env = cpu_child_env()
     code = _SCRIPT % {"steps": 3 if smoke else 10,
                       "rounds": 3 if smoke else 6,
                       "depth": depth}

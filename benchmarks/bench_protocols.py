@@ -17,9 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import Table
+from benchmarks.common import Table, cpu_child_env
 from repro.core import costmodel, topology_from_mesh_shape
-from repro.core.topology import DCN_BW, ICI_BW
 
 
 def run() -> list:
@@ -63,12 +62,9 @@ def run() -> list:
 def _compiled_check() -> Table:
     import subprocess
     import sys
-    import os
     t = Table("bench_protocols: compiled schedules (8 host devices)",
               ["protocol", "HLO collective ops", "wall us (1MB AR)"])
     code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, time, re
 from functools import partial
 from jax.sharding import PartitionSpec as P
@@ -92,10 +88,7 @@ for proto in ("xla_default", "ring", "bidir_ring", "recursive_doubling", "recurs
         t0 = time.perf_counter_ns(); jax.block_until_ready(jf(x)); ts.append((time.perf_counter_ns()-t0)/1e3)
     print(f"{proto},{ops},{np.median(ts):.0f}")
 """
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "src")
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
+    proc = subprocess.run([sys.executable, "-c", code], env=cpu_child_env(),
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
         t.add("(subprocess failed)", proc.stderr[-200:], "")

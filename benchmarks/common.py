@@ -3,11 +3,34 @@ and HLO-structure measurements, roofline terms come from the dry-run)."""
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Callable, Dict, List
 
 import jax
 import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def cpu_child_env(devices: int = 8) -> dict:
+    """Environment for a bench child process that emulates ``devices``
+    devices on the host CPU.  Such a child counts bytes and collectives;
+    it measures nothing on a chip.  Where this process sees a TPU it
+    holds it, so the bench refuses to start instead of spawning children
+    that would compete for the chip."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        raise RuntimeError(
+            "this bench runs child processes on emulated CPU devices and "
+            "refuses to start where a TPU is visible; on the chip run "
+            "`python chip_smoke.py`")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = (os.path.join(REPO, "src") + os.pathsep
+                         + env.get("PYTHONPATH", ""))
+    return env
 
 
 def time_python(fn: Callable, repeat: int = 200, warmup: int = 5) -> float:

@@ -17,7 +17,12 @@ import os
 from typing import Dict, List, Optional
 
 from benchmarks.common import Table
-from repro.core.topology import DCN_BW, HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.core.topology import DEVICES, V5E
+
+PEAK_FLOPS_BF16 = DEVICES[V5E].peak_flops_bf16
+HBM_BW = DEVICES[V5E].hbm_bw
+ICI_BW = DEVICES[V5E].ici.bandwidth
+DCN_BW = DEVICES[V5E].dcn.bandwidth
 
 # ~50 GB/s/link; a v5e chip drives 4 ICI links concurrently on the torus,
 # but a single collective schedule typically saturates 2 (bidirectional

@@ -334,7 +334,7 @@ class CollectiveEngine:
     @staticmethod
     def _chunked(x: jax.Array, p: int) -> Tuple[jax.Array, int, tuple]:
         flat, n = c.pad_flat(x, p)
-        return flat.reshape(p, -1), n, x.shape
+        return c.chunk_view(flat, p), n, x.shape
 
     # ------------------------------------------------------------------
     # The function set (paper's "MPI functions")
@@ -433,7 +433,7 @@ class CollectiveEngine:
             shard = twophase.two_phase_start(x2d, axes[0])
             fin = lambda: c.unpad(
                 twophase.two_phase_finish(shard, axes[0], axes[1],
-                                          x2d.shape[0], x2d.shape[1]),
+                                          x2d.shape[0], c.chunk_size(x2d)),
                 n, shape)
             sb, wb = plan_mod.phase_wire_bytes(costmodel.TWO_PHASE_2D, p0, nb)
             return InFlight(fn, axes, fin, costmodel.TWO_PHASE_2D, sb, wb)
@@ -883,6 +883,7 @@ class CollectiveEngine:
             y = self._allreduce_1d(x, axis, proto=proto)
             y2d, _, _ = self._chunked(y, p)
             chunk = c.dyn_chunk(y2d, c.axis_index(axis))
+        chunk = chunk.reshape(-1)
         return InFlight(fn, (axis,), lambda: chunk, proto, sb, 0)
 
     def _zero_ag_start(self, shard: jax.Array, axis: str) -> InFlight:
@@ -900,9 +901,9 @@ class CollectiveEngine:
         if proto == costmodel.RECURSIVE_DOUBLING:
             buf = recursive.doubling_all_gather_flat(flat, axis)
         elif proto == costmodel.BIDIR_RING:
-            buf = ring.bidir_ring_all_gather_flat(flat, axis)
+            buf = ring.bidir_ring_all_gather_flat(c.rows(flat), axis)
         else:
-            buf = ring.ring_all_gather_flat(flat, axis)
+            buf = ring.ring_all_gather_flat(c.rows(flat), axis)
         return InFlight(fn, (axis,), lambda: buf.reshape(-1), proto, sb, 0)
 
     def zero_reduce_scatter_start(self, g: jax.Array, axis_name, *,

@@ -9,6 +9,8 @@ of the paper's "MPI-protocol offloaded to the MPI-network".
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -64,6 +66,34 @@ def pad_flat(x: jax.Array, multiple: int):
 
 def unpad(flat: jax.Array, n: int, shape) -> jax.Array:
     return flat[:n].reshape(shape)
+
+
+LANES = 128
+
+
+def _row_shape(n: int) -> tuple:
+    return (n // LANES, LANES) if n % (2 * LANES) == 0 else (n,)
+
+
+def rows(flat: jax.Array) -> jax.Array:
+    """A flat chunk as ``(c // 128, 128)`` rows where ``c`` is a multiple
+    of 2 * 128, else unchanged.  The TPU compiler takes minutes to lay
+    out an array whose minor dimension holds ~1e8 elements (a ``(p, c)``
+    view of a large gradient), while 128-lane rows are a plain relayout.
+    The even row count keeps the halves the bidirectional ring splits a
+    chunk into the same elements as on the flat chunk."""
+    return flat.reshape(_row_shape(flat.shape[0]))
+
+
+def chunk_view(flat: jax.Array, p: int) -> jax.Array:
+    """``(p, ...)`` view of a padded flat vector: row ``j`` is chunk ``j``,
+    laid out as ``rows`` lays out one chunk."""
+    return flat.reshape((p,) + _row_shape(flat.shape[0] // p))
+
+
+def chunk_size(x2d: jax.Array) -> int:
+    """Elements per chunk of a ``chunk_view``."""
+    return math.prod(x2d.shape[1:])
 
 
 def dyn_chunk(x2d: jax.Array, idx) -> jax.Array:

@@ -37,13 +37,16 @@ def _combine(acc: jax.Array, contrib: jax.Array,
         # compiles on TPU and falls back to the jnp oracle elsewhere
         # (interpret mode is test-only — see repro.kernels.local_reduce.ops).
         from repro.kernels.local_reduce import ops as lr_ops
-        return lr_ops.sum_chunks(jnp.stack([acc, contrib]), dtype=acc.dtype)
+        return lr_ops.sum_chunks(
+            jnp.stack([acc.reshape(-1), contrib.reshape(-1)]),
+            dtype=acc.dtype).reshape(acc.shape)
     return acc + contrib
 
 
 def ring_reduce_scatter_flat(x2d: jax.Array, axis_name: str,
                              use_kernel: bool = False) -> jax.Array:
-    """x2d: (p, chunk) per device.  Returns this device's fully-reduced chunk.
+    """x2d: (p, ...) per device, row j = chunk j (``common.chunk_view``).
+    Returns this device's fully-reduced chunk.
 
     Device i ends with sum_j x2d[j-th device][i].  p-1 steps, (p-1)/p * n
     bytes per device: bandwidth-optimal.

@@ -34,7 +34,7 @@ def two_phase_finish(shard: jax.Array, axis0: str, axis1: str,
     (p0 * chunk,)."""
     p1 = c.axis_size(axis1)
     shard2d, n = c.pad_flat(shard, p1)
-    shard2d = shard2d.reshape(p1, -1)
+    shard2d = c.chunk_view(shard2d, p1)
     reduced = ring.bidir_ring_all_reduce_flat(shard2d, axis1)
     shard = c.unpad(reduced.reshape(-1), n, shard.shape)
     gathered = ring.bidir_ring_all_gather_flat(shard, axis0)
@@ -50,7 +50,8 @@ def two_phase_all_reduce_2d(
     x2d: (p0, chunk) view of the payload.  Returns flat (p0 * chunk,).
     """
     shard = two_phase_start(x2d, axis0)
-    return two_phase_finish(shard, axis0, axis1, x2d.shape[0], x2d.shape[1])
+    return two_phase_finish(shard, axis0, axis1, x2d.shape[0],
+                            c.chunk_size(x2d))
 
 
 def hierarchical_start(

@@ -6,9 +6,8 @@ fixed (ICI torus within a pod, DCN between pods), so the co-design runs in
 the other direction: the protocol layer reads an explicit topology model and
 specializes per function.  This module is that topology model.
 
-Hardware constants are for the grading target (TPU v5e-class):
-  197 TFLOP/s bf16 / chip, 819 GB/s HBM, ~50 GB/s per ICI link,
-  DCN between pods modeled at 6.25 GB/s per host link.
+Link constants are looked up by the devices' ``device_kind`` in one table
+(``DEVICES``); a kind that is not in it is an error, never a default.
 """
 
 from __future__ import annotations
@@ -17,12 +16,7 @@ import dataclasses
 import math
 from typing import Mapping, Sequence
 
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link per direction
-DCN_BW = 6.25e9           # bytes/s per host across pods
-ICI_ALPHA = 1e-6          # per-hop latency, seconds
-DCN_ALPHA = 10e-6         # cross-pod latency, seconds
+from jax.sharding import Mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,30 +90,71 @@ class Topology:
         return " x ".join(parts)
 
 
-def ici_link() -> Link:
-    return Link(bandwidth=ICI_BW, alpha=ICI_ALPHA, wraparound=True)
+@dataclasses.dataclass(frozen=True)
+class DeviceSpec:
+    """Per-chip peaks and the link classes a mesh of these chips rides."""
+
+    peak_flops_bf16: float   # FLOP/s per chip
+    hbm_bw: float            # bytes/s per chip
+    ici: Link                # neighbour link along an in-pod mesh axis
+    dcn: Link                # per-host link across pods
+    source: str
 
 
-def dcn_link() -> Link:
-    return Link(bandwidth=DCN_BW, alpha=DCN_ALPHA, wraparound=False)
+#: ``device_kind`` as JAX reports it for a TPU v5e chip.
+V5E = "TPU v5 lite"
+
+_V5E_SPEC = DeviceSpec(
+    peak_flops_bf16=197e12, hbm_bw=819e9,
+    # 1,600 Gbit/s of ICI per chip over 4 links = 50 GB/s per link
+    ici=Link(bandwidth=50e9, alpha=1e-6, wraparound=True),
+    dcn=Link(bandwidth=6.25e9, alpha=10e-6, wraparound=False),
+    source="Google Cloud TPU v5e documentation (peaks, HBM, ICI "
+           "bandwidth); ICI/DCN latencies and DCN bandwidth are assumed, "
+           "not measured")
+
+DEVICES: Mapping[str, DeviceSpec] = {
+    V5E: _V5E_SPEC,
+    # The CPU test host's virtual devices have no links.  They plan with
+    # the v5e's constants so tests exercise the protocol choices the chip
+    # makes; nothing timed on the CPU is a link measurement.
+    "cpu": dataclasses.replace(_V5E_SPEC,
+                               source="test host: the TPU v5e entry"),
+}
+
+
+def device_spec(device_kind: str) -> DeviceSpec:
+    try:
+        return DEVICES[device_kind]
+    except KeyError:
+        raise KeyError(f"no link constants for device kind "
+                       f"{device_kind!r}; known: {sorted(DEVICES)}") from None
 
 
 def topology_from_mesh_shape(
-    axis_names: Sequence[str], axis_sizes: Sequence[int]
+    axis_names: Sequence[str], axis_sizes: Sequence[int],
+    device_kind: str = V5E,
 ) -> Topology:
-    """Build the physical model for a production mesh.
+    """Build the physical model for a mesh of ``device_kind`` chips
+    (default: the v5e, the planning target of device-less meshes).
 
     Any axis named ``pod`` is DCN; everything else is ICI torus.
     """
+    spec = device_spec(device_kind)
     sizes = dict(zip(axis_names, axis_sizes))
-    links = {
-        name: dcn_link() if name == "pod" else ici_link() for name in axis_names
-    }
+    links = {name: spec.dcn if name == "pod" else spec.ici
+             for name in axis_names}
     return Topology(axis_sizes=sizes, axis_links=links)
 
 
 def topology_from_mesh(mesh) -> Topology:
-    # mesh.shape (name -> size) exists on both Mesh and AbstractMesh;
-    # .devices does not exist on abstract meshes.
+    """The model for a concrete mesh takes its devices' kind; an abstract
+    mesh with no device kind plans for the v5e."""
     sizes = dict(mesh.shape)
-    return topology_from_mesh_shape(tuple(sizes), tuple(sizes.values()))
+    if isinstance(mesh, Mesh):
+        kind = mesh.devices.flat[0].device_kind
+    else:
+        device = mesh.abstract_device
+        kind = device.device_kind if device is not None else V5E
+    return topology_from_mesh_shape(tuple(sizes), tuple(sizes.values()),
+                                    device_kind=kind)

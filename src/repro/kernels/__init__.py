@@ -2,7 +2,7 @@
 
 Each kernel subpackage follows the pattern:
   kernel.py — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling (TPU target)
-  ops.py    — jit'd public wrapper (auto interpret=True off-TPU)
+  ops.py    — public wrapper (kernel on TPU; interpret only on request)
   ref.py    — pure-jnp oracle used by tests and as the CPU fallback
 """
 

@@ -1,10 +1,11 @@
 """Public attention op: (B, S, H, D) layout, GQA-aware, kernel/oracle switch.
 
-``attention`` is what the model layers call.  It routes to the Pallas
-kernel on TPU (or in interpret mode when forced by tests) and to the exact
-jnp oracle elsewhere.  The custom-VJP backward recomputes attention with
-the oracle (flash backward is a follow-up kernel; recompute-backward is
-the standard remat policy at these sizes anyway).
+``attention`` routes to the Pallas kernel on TPU and to the exact jnp
+oracle elsewhere; ``force_kernel`` overrides the choice.  The kernel
+compiles unless the caller passes ``interpret=True`` (the CPU tests).
+The custom-VJP backward recomputes attention with the oracle (flash
+backward is a follow-up kernel; recompute-backward is the standard remat
+policy at these sizes anyway).
 """
 
 from __future__ import annotations
@@ -35,6 +36,7 @@ def _unflatten(x, b):  # (B*H, S, D) -> (B, S, H, D)
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, sm_scale: float | None = None,
               q_offset: int = 0, force_kernel: bool | None = None,
+              interpret: bool = False,
               block_q: int = K.DEFAULT_BLOCK_Q,
               block_k: int = K.DEFAULT_BLOCK_K) -> jax.Array:
     """q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D); returns (B, Sq, Hq, D)."""
@@ -45,7 +47,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out = K.flash_attention_bhsd(
             qf, kf, vf, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, q_offset=q_offset,
-            interpret=not _on_tpu())
+            interpret=interpret)
     else:
         out = ref.attention(qf, kf, vf, causal=causal, sm_scale=sm_scale,
                             q_offset=q_offset)

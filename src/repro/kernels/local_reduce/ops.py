@@ -14,8 +14,10 @@ def _on_tpu() -> bool:
 
 
 def sum_chunks(x: jax.Array, dtype=None,
-               force_kernel: bool | None = None) -> jax.Array:
-    """x: (k, n) -> (n,) sum accumulated in f32."""
+               force_kernel: bool | None = None,
+               interpret: bool = False) -> jax.Array:
+    """x: (k, n) -> (n,) sum accumulated in f32.  The kernel runs on TPU
+    (or when ``force_kernel``), compiled unless ``interpret=True``."""
     dtype = dtype or x.dtype
     use_kernel = force_kernel if force_kernel is not None else _on_tpu()
     if not use_kernel:
@@ -25,5 +27,5 @@ def sum_chunks(x: jax.Array, dtype=None,
     pad = (-n) % tile
     xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
     x3 = xp.reshape(k, -1, K.LANES)
-    out = K.sum_chunks_3d(x3, interpret=not _on_tpu())
+    out = K.sum_chunks_3d(x3, interpret=interpret)
     return out.reshape(-1)[:n].astype(dtype)

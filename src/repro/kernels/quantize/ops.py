@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the quantize kernel (flat-array API).
 
-On a TPU backend the Pallas kernel runs compiled; elsewhere it runs in
-interpret mode only when explicitly requested (tests), defaulting to the
-jnp oracle which XLA-CPU fuses well anyway.
+On a TPU backend the Pallas kernel runs; elsewhere the jnp oracle, which
+XLA-CPU fuses well anyway.  ``force_kernel`` overrides the choice, and the
+kernel compiles unless the caller passes ``interpret=True`` (the CPU
+tests).
 """
 
 from __future__ import annotations
@@ -29,7 +30,7 @@ def _mode(force_kernel: bool | None) -> str:
 
 
 def quantize(x: jax.Array, block: int = QBLOCK,
-             force_kernel: bool | None = None
+             force_kernel: bool | None = None, interpret: bool = False
              ) -> Tuple[jax.Array, jax.Array]:
     """Flat x (n,), n % block == 0 -> (q int8 (n,), scales f32 (n/block,))."""
     assert block == QBLOCK, f"kernel is specialized for block={QBLOCK}"
@@ -43,12 +44,13 @@ def quantize(x: jax.Array, block: int = QBLOCK,
     if pad_rows:
         x2d = jnp.concatenate(
             [x2d, jnp.zeros((pad_rows, block), jnp.float32)])
-    q2d, s2d = K.quantize_2d(x2d, interpret=not _on_tpu())
+    q2d, s2d = K.quantize_2d(x2d, interpret=interpret)
     return q2d[:rows].reshape(-1), s2d[:rows, 0]
 
 
 def dequantize(q: jax.Array, scale: jax.Array, block: int = QBLOCK,
-               dtype=jnp.float32, force_kernel: bool | None = None
+               dtype=jnp.float32, force_kernel: bool | None = None,
+               interpret: bool = False
                ) -> jax.Array:
     assert block == QBLOCK
     mode = _mode(force_kernel)
@@ -61,12 +63,13 @@ def dequantize(q: jax.Array, scale: jax.Array, block: int = QBLOCK,
     if pad_rows:
         q2d = jnp.concatenate([q2d, jnp.zeros((pad_rows, block), jnp.int8)])
         s2d = jnp.concatenate([s2d, jnp.ones((pad_rows, 1), jnp.float32)])
-    x2d = K.dequantize_2d(q2d, s2d, dtype=dtype, interpret=not _on_tpu())
+    x2d = K.dequantize_2d(q2d, s2d, dtype=dtype, interpret=interpret)
     return x2d[:rows].reshape(-1)
 
 
 def dequant_add(acc: jax.Array, q: jax.Array, scale: jax.Array,
-                block: int = QBLOCK, force_kernel: bool | None = None
+                block: int = QBLOCK, force_kernel: bool | None = None,
+                interpret: bool = False
                 ) -> jax.Array:
     assert block == QBLOCK
     mode = _mode(force_kernel)
@@ -81,5 +84,5 @@ def dequant_add(acc: jax.Array, q: jax.Array, scale: jax.Array,
         a2d = jnp.concatenate([a2d, jnp.zeros((pad_rows, block), acc.dtype)])
         q2d = jnp.concatenate([q2d, jnp.zeros((pad_rows, block), jnp.int8)])
         s2d = jnp.concatenate([s2d, jnp.ones((pad_rows, 1), jnp.float32)])
-    out = K.dequant_add_2d(a2d, q2d, s2d, interpret=not _on_tpu())
+    out = K.dequant_add_2d(a2d, q2d, s2d, interpret=interpret)
     return out[:rows].reshape(acc.shape)

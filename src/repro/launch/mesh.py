@@ -7,8 +7,7 @@ the engine's topology model.
 
 Functions, not module constants: importing this module never touches JAX
 device state (the dry-run sets XLA_FLAGS before any JAX import).
-Construction goes through the device substrate so the same definitions
-work on any supported JAX version.
+Construction goes through the device substrate.
 """
 
 from __future__ import annotations

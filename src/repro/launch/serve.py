@@ -1,5 +1,6 @@
-"""Serving driver: continuous-batching generation on a reduced model,
-optionally supervised by the elastic ``ServeController``.
+"""Serving driver: continuous-batching generation (``--reduced`` toy
+config by default, ``--full`` for the published one), optionally
+supervised by the elastic ``ServeController``.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-72b \
         --requests 16 --batch 4 --max-new 12
@@ -13,8 +14,10 @@ optionally supervised by the elastic ``ServeController``.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import logging
 import time
+from typing import Any, List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -23,20 +26,25 @@ from repro import comm as comm_mod
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
-from repro.runtime import ctrlplane, health
+from repro.runtime import compile_cache, ctrlplane, health
 from repro.runtime.controller import FaultPlan
 from repro.serve import BatchScheduler, Request, ServeCfg, ServeController
 
 logger = logging.getLogger("repro.serve")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="qwen2-72b")
+    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-len", type=int, default=128)
+    ap.add_argument("--prompt-lens", default="",
+                    help="comma-separated prompt lengths, cycled over the "
+                         "requests (default: random lengths 4-15)")
     ap.add_argument("--seed", type=int, default=0,
                     help="sampling seed (ServeCfg.seed)")
     ap.add_argument("--max-queue", type=int, default=None,
@@ -82,15 +90,44 @@ def main() -> None:
     ap.add_argument("--ctrl-fault-plan", default="",
                     help="injected control-plane message faults, e.g. "
                          "'drop@3:2,partition@0:40'")
-    args = ap.parse_args()
-    logging.basicConfig(level=logging.INFO)
+    return ap
 
-    cfg = get_config(args.arch, reduced=True)
+
+def make_requests(args: argparse.Namespace, vocab_size: int
+                  ) -> List[Request]:
+    """Seeded random prompts: lengths from ``--prompt-lens`` (cycled) or
+    drawn in [4, 16)."""
+    rng = np.random.RandomState(0)
+    lens = [int(n) for n in args.prompt_lens.split(",") if n]
+    requests = []
+    for rid in range(args.requests):
+        n = lens[rid % len(lens)] if lens else rng.randint(4, 16)
+        requests.append(Request(rid=rid,
+                                prompt=rng.randint(0, vocab_size,
+                                                   size=n).tolist(),
+                                max_new=args.max_new))
+    return requests
+
+
+@dataclasses.dataclass
+class ServeRun:
+    """Result of ``serve``: the model it built, the requests that
+    completed and were shed, and host wall seconds."""
+    model: Any
+    params: Any
+    scfg: ServeCfg
+    done: List[Request]
+    shed: List[Request]
+    seconds: float
+
+
+def serve(args: argparse.Namespace) -> ServeRun:
+    cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg)
     if model.kind == "encdec":
         raise SystemExit("serve driver targets decoder LMs; "
                          "see examples/serving.py for enc-dec")
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     logger.info("model %s: %.2fM params", model.name,
                 model.param_count() / 1e6)
 
@@ -100,18 +137,12 @@ def main() -> None:
     logger.info("serving session: %s", session.world.describe())
 
     scfg = ServeCfg(max_len=args.max_len, batch=args.batch,
-                    cache_dtype=jax.numpy.float32, seed=args.seed,
+                    cache_dtype=cfg.param_dtype, seed=args.seed,
                     max_queue=args.max_queue,
                     page_tokens=args.page_tokens,
                     pool_pages=args.pool_pages,
                     chunked_prefill=not args.no_chunked_prefill)
-    rng = np.random.RandomState(0)
-    requests = [
-        Request(rid=rid,
-                prompt=rng.randint(0, cfg.vocab_size,
-                                   size=rng.randint(4, 16)).tolist(),
-                max_new=args.max_new)
-        for rid in range(args.requests)]
+    requests = make_requests(args, cfg.vocab_size)
 
     t0 = time.time()
     if args.elastic:
@@ -171,6 +202,15 @@ def main() -> None:
                 total_tokens / dt)
     for r in done[:4]:
         logger.info("req %d: %s", r.rid, r.generated)
+    return ServeRun(model=model, params=params, scfg=scfg, done=done,
+                    shed=shed, seconds=dt)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
+    logging.basicConfig(level=logging.INFO)
+    compile_cache.enable()
+    serve(args)
 
 
 if __name__ == "__main__":

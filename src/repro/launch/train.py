@@ -19,9 +19,11 @@ injection on fake host devices (``XLA_FLAGS=
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import logging
 import time
-from functools import partial
+from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +39,7 @@ from repro.optim import cosine_schedule, make_optimizer
 from repro.parallel.sharding import named_shardings
 from repro.runtime import (ElasticController, FaultPlan, StepWatchdog,
                            substrate)
-from repro.runtime import ctrlplane, health
+from repro.runtime import compile_cache, ctrlplane, health
 from repro.train import trainer
 
 logger = logging.getLogger("repro.train")
@@ -73,7 +75,7 @@ def build_session(mesh, model, opt, ds, args) -> "comm_mod.Session":
         probe_step, abstate, abatch, mesh=mesh, probe=probe)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="granite-34b")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -162,17 +164,36 @@ def main() -> None:
     ap.add_argument("--ctrl-fault-plan", default="",
                     help="injected control-plane message faults, e.g. "
                          "'drop@3:2,delay@5:4,partition@0:40'")
-    args = ap.parse_args()
+    return ap
 
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.zero and args.sync != "composed":
         ap.error("--zero needs --sync composed (the RS/AG seam only "
                  "exists on the composed planned-collective path)")
     if args.zero and args.bucket_grads:
         ap.error("--zero runs one RS/AG pair per parameter leaf and is "
                  "incompatible with --bucket-grads")
+    if args.elastic and not args.ckpt_dir:
+        ap.error("--elastic needs --ckpt-dir (recovery restores from "
+                 "the atomic checkpoint store)")
+    return args
 
-    logging.basicConfig(level=logging.INFO)
 
+@dataclasses.dataclass
+class Setup:
+    """What both loops build from the arguments."""
+    model: Any
+    optimizer: Any
+    tcfg: "trainer.TrainCfg"
+    mesh: Any
+    dataset: SyntheticLMDataset
+    comm_session: Optional["comm_mod.Session"]
+
+
+def setup(args: argparse.Namespace) -> Setup:
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg)
     mesh = (make_production_mesh() if args.production_mesh
@@ -200,69 +221,89 @@ def main() -> None:
     if args.sync != "auto":
         comm_session = build_session(mesh, model, opt, ds, args)
         logger.info("composed session:\n%s", comm_session.describe())
+    return Setup(model, opt, tcfg, mesh, ds, comm_session)
 
-    if args.elastic:
-        if not args.ckpt_dir:
-            ap.error("--elastic needs --ckpt-dir (recovery restores from "
-                     "the atomic checkpoint store)")
-        session = trainer.TrainSession(model, opt, tcfg)
-        fplan = (FaultPlan.parse(args.fault_plan, seed=args.fault_seed)
-                 if args.fault_plan else None)
-        # SIGTERM (what cloud schedulers send ahead of eviction) becomes
-        # a step-boundary drain + re-mesh instead of a corpse.
-        notice = health.PreemptionNotice()
-        try:
-            health.install_preemption_handler(notice)
-        except ValueError:                  # not the main thread
-            logger.warning("not on the main thread: SIGTERM preemption "
-                           "handler not installed")
-        membership = None
-        if args.ctrl_peers:
-            cplan = (ctrlplane.CtrlFaultPlan.parse(args.ctrl_fault_plan,
-                                                   seed=args.fault_seed)
-                     if args.ctrl_fault_plan else None)
-            membership = ctrlplane.connect(
-                args.ctrl_member or None,
-                port=args.ctrl_port, host=args.ctrl_host,
-                peers=args.ctrl_peers,
-                config=ctrlplane.CtrlConfig(
-                    heartbeat_interval=args.heartbeat_interval,
-                    heartbeat_timeout=5 * args.heartbeat_interval),
-                fault_plan=cplan)
-            logger.info("control plane: %s with peers %s",
-                        membership.member, membership.peers)
-        try:
-            ctl = ElasticController(
-                session, ds, mesh, total_steps=args.steps,
-                ckpt_dir=args.ckpt_dir, comm=comm_session,
-                ckpt_every=args.ckpt_every,
-                ckpt_sharded=args.ckpt_sharded,
-                fault_plan=fplan,
-                max_recoveries=args.max_recoveries,
-                watchdog_timeout=args.watchdog_timeout,
-                preemption=notice, membership=membership,
-                on_step=lambda s, l: (s % args.log_every == 0
-                                      and logger.info("step %4d  "
-                                                      "loss %.4f", s, l)))
-            report = ctl.run()
-        finally:
-            if membership is not None:
-                membership.close()
-        logger.info("elastic run done:\n%s", report.describe())
-        if comm_session is not None:
-            logger.info("session stats:\n%s", comm_session.finalize())
-        return
 
+def train_elastic(args: argparse.Namespace, s: Setup):
+    """The supervised fail/shrink/grow loop (``--elastic``); returns the
+    controller's report."""
+    session = trainer.TrainSession(s.model, s.optimizer, s.tcfg)
+    fplan = (FaultPlan.parse(args.fault_plan, seed=args.fault_seed)
+             if args.fault_plan else None)
+    # SIGTERM (what cloud schedulers send ahead of eviction) becomes
+    # a step-boundary drain + re-mesh instead of a corpse.
+    notice = health.PreemptionNotice()
+    try:
+        health.install_preemption_handler(notice)
+    except ValueError:                  # not the main thread
+        logger.warning("not on the main thread: SIGTERM preemption "
+                       "handler not installed")
+    membership = None
+    if args.ctrl_peers:
+        cplan = (ctrlplane.CtrlFaultPlan.parse(args.ctrl_fault_plan,
+                                               seed=args.fault_seed)
+                 if args.ctrl_fault_plan else None)
+        membership = ctrlplane.connect(
+            args.ctrl_member or None,
+            port=args.ctrl_port, host=args.ctrl_host,
+            peers=args.ctrl_peers,
+            config=ctrlplane.CtrlConfig(
+                heartbeat_interval=args.heartbeat_interval,
+                heartbeat_timeout=5 * args.heartbeat_interval),
+            fault_plan=cplan)
+        logger.info("control plane: %s with peers %s",
+                    membership.member, membership.peers)
+    try:
+        ctl = ElasticController(
+            session, s.dataset, s.mesh, total_steps=args.steps,
+            ckpt_dir=args.ckpt_dir, comm=s.comm_session,
+            ckpt_every=args.ckpt_every,
+            ckpt_sharded=args.ckpt_sharded,
+            fault_plan=fplan,
+            max_recoveries=args.max_recoveries,
+            watchdog_timeout=args.watchdog_timeout,
+            preemption=notice, membership=membership,
+            on_step=lambda st, l: (st % args.log_every == 0
+                                   and logger.info("step %4d  "
+                                                   "loss %.4f", st, l)))
+        report = ctl.run()
+    finally:
+        if membership is not None:
+            membership.close()
+    logger.info("elastic run done:\n%s", report.describe())
+    if s.comm_session is not None:
+        logger.info("session stats:\n%s", s.comm_session.finalize())
+    return report
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """Result of ``train``: per-step losses, the final state, and host
+    wall times (compile, first step, mean of the later steps)."""
+    losses: List[float]
+    state: Any
+    compile_s: float
+    first_step_s: float
+    warm_step_s: float
+
+
+def train(args: argparse.Namespace, s: Setup) -> TrainRun:
+    """The plain training loop (no controller): AOT-compile the step,
+    run ``args.steps`` steps, checkpoint as asked."""
+    mesh, tcfg = s.mesh, s.tcfg
     step_fn = trainer.make_train_step(
-        model, opt, tcfg, mesh=mesh,
-        comm=comm_session.world if comm_session is not None else None)
-    sspecs = trainer.state_specs(model, opt, tcfg, mesh=mesh)
+        s.model, s.optimizer, tcfg, mesh=mesh,
+        comm=s.comm_session.world if s.comm_session is not None else None)
+    shardings = named_shardings(
+        mesh, trainer.state_specs(s.model, s.optimizer, tcfg, mesh=mesh))
 
     with substrate.set_mesh(mesh):
-        state = trainer.make_train_state(model, opt, jax.random.PRNGKey(0),
-                                         cfg=tcfg, mesh=mesh)
-        state = jax.device_put(state, named_shardings(mesh, sspecs))
-        jstep = jax.jit(step_fn, donate_argnums=0)
+        # built in place on its shardings: an eager init would first hold
+        # the whole state on one device
+        state = jax.jit(
+            functools.partial(trainer.make_train_state, s.model,
+                              s.optimizer, cfg=tcfg, mesh=mesh),
+            out_shardings=shardings)(jax.random.PRNGKey(0))
 
         ckpt = (CheckpointManager(args.ckpt_dir, every=args.ckpt_every,
                                   sharded=args.ckpt_sharded)
@@ -270,34 +311,67 @@ def main() -> None:
         start = 0
         if ckpt is not None:
             restored, rstep = ckpt.restore_latest(
-                jax.eval_shape(lambda: state),
-                named_shardings(mesh, sspecs),
+                jax.eval_shape(lambda: state), shardings,
                 allow_resize_1d=tcfg.zero)
             if restored is not None:
                 state, start = restored, rstep
                 logger.info("restored checkpoint at step %d", start)
 
-        wd = StepWatchdog(timeout=300.0).start()
-        t0 = time.time()
+        # the state keeps its layout across steps, so the one compiled
+        # executable serves every step
+        t0 = time.perf_counter()
+        jstep = jax.jit(step_fn, out_shardings=(shardings, None),
+                        donate_argnums=0).lower(
+            state, s.dataset.sharded_batch(start, mesh)).compile()
+        compile_s = time.perf_counter() - t0
+        logger.info("train step compiled in %.1fs", compile_s)
+
+        wd = StepWatchdog(timeout=args.watchdog_timeout).start()
+        losses = []
+        t0 = time.perf_counter()
+        t_first = None
         for step in range(start, args.steps):
-            batch = ds.sharded_batch(step, mesh)
+            batch = s.dataset.sharded_batch(step, mesh)
             state, metrics = jstep(state, batch)
+            losses.append(metrics["loss"])
             wd.beat()
             if ckpt is not None:
                 ckpt.maybe_save(step + 1, state)
+            if t_first is None:
+                jax.block_until_ready(metrics)
+                t_first = time.perf_counter()
             if step % args.log_every == 0 or step == args.steps - 1:
                 logger.info("step %4d  loss %.4f  |g| %.3f  lr %.2e  "
                             "(%.2fs/step)",
                             step, float(metrics["loss"]),
                             float(metrics.get("grad_norm", 0.0)),
                             float(metrics.get("lr", 0.0)),
-                            (time.time() - t0) / max(step - start + 1, 1))
+                            (time.perf_counter() - t0)
+                            / max(step - start + 1, 1))
+        jax.block_until_ready(state)
+        t_end = time.perf_counter()
         wd.stop()
         if ckpt is not None:
             ckpt.maybe_save(args.steps, state, force=True)
             ckpt.wait()
-        if comm_session is not None:
-            logger.info("session stats:\n%s", comm_session.finalize())
+        if s.comm_session is not None:
+            logger.info("session stats:\n%s", s.comm_session.finalize())
+    n = len(losses)
+    return TrainRun(
+        losses=[float(l) for l in losses], state=state, compile_s=compile_s,
+        first_step_s=(t_first - t0) if t_first is not None else 0.0,
+        warm_step_s=(t_end - t_first) / (n - 1) if n > 1 else 0.0)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    logging.basicConfig(level=logging.INFO)
+    compile_cache.enable()
+    s = setup(args)
+    if args.elastic:
+        train_elastic(args, s)
+    else:
+        train(args, s)
 
 
 if __name__ == "__main__":

@@ -121,13 +121,19 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
 
     x: (B, S, H, P); dt: (B, S, H); A: (H,) negative; Bm/Cm: (B, S, G, N).
     h0: optional initial state (B, H, P, N).  Returns (y (B,S,H,P),
-    h_final (B,H,P,N)).
+    h_final (B,H,P,N)).  A sequence that is not a multiple of ``chunk`` is
+    right-padded with dt = 0 steps, which neither decay nor feed the state.
     """
+    s_in = x.shape[1]
+    pad = (-s_in) % chunk
+    if pad:
+        def rpad(a):
+            return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        x, dt, Bm, Cm = rpad(x), rpad(dt), rpad(Bm), rpad(Cm)
     b, s, h, pdim = x.shape
     g, n = Bm.shape[2], Bm.shape[3]
     rep = h // g
     nc = s // chunk
-    assert nc * chunk == s, (s, chunk)
 
     xc = x.reshape(b, nc, chunk, h, pdim).astype(jnp.float32)
     dtc = dt.reshape(b, nc, chunk, h).astype(jnp.float32)
@@ -172,7 +178,7 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     state_decay_in = jnp.exp(cs_full)                      # (B,nc,H,Q)
     y_off = jnp.einsum("bcqhn,bchpn,bchq->bcqhp",
                        Ch, h_prevs, state_decay_in)
-    y = (y_diag + y_off).reshape(b, s, h, pdim)
+    y = (y_diag + y_off).reshape(b, s, h, pdim)[:, :s_in]
     return y.astype(x.dtype), h_final
 
 

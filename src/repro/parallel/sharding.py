@@ -41,30 +41,29 @@ def active_mesh():
 
 def auto_axis_names(mesh) -> tuple:
     """Mesh axes currently in Auto mode (constrainable).  Inside a
-    shard_map body the manual axes must not appear in constraints.  On
-    JAX without an axis-type concept every axis is Auto."""
+    shard_map body the manual axes must not appear in constraints."""
     return substrate.auto_axis_names(mesh)
 
 
 def shard_hint(x: jax.Array, spec: P) -> jax.Array:
-    """Best-effort sharding constraint: identity without a mesh context
-    (or where the backend cannot resolve bare specs, e.g. abstract-mesh
-    tracing on legacy JAX).
+    """Best-effort sharding constraint: identity without a mesh context.
 
-    On *concrete* values — eager execution, where with_sharding_constraint
-    lowers to jit(identity, out_shardings=...) and jax enforces exact
-    divisibility — spec entries whose mesh-axis product does not divide
-    the dim are dropped: the serving tier's un-jitted batch-1 prefill runs
-    the same model code under a data-parallel mesh.  Under tracing the
-    spec is applied as-is (hints are load-bearing for the partitioner and
-    per-shard shapes inside vmap-emulated manual regions would fail a
-    naive divisibility test)."""
+    Under tracing the spec is applied as-is against the active mesh
+    (hints are load-bearing for the partitioner).  On *concrete* values —
+    eager execution, e.g. the serving tier's un-jitted batch-1 prefill —
+    the constraint is a ``NamedSharding`` on the device mesh that
+    ``set_mesh`` entered (identity under an abstract-only context), and
+    spec entries whose mesh-axis product does not divide the dim are
+    dropped, because placing a concrete array needs exact divisibility."""
     mesh = active_mesh()
-    if not substrate.supports_spec_constraint(mesh):
+    if mesh is None:
         return x
     fs = filter_spec(spec, auto_axis_names(mesh))
     if isinstance(x, jax.core.Tracer):
         return jax.lax.with_sharding_constraint(x, fs)
+    devices = substrate.concrete_mesh()
+    if devices is None:
+        return x
     sizes = dict(mesh.shape)
     out = []
     for i, entry in enumerate(fs):
@@ -75,7 +74,8 @@ def shard_hint(x: jax.Array, spec: P) -> jax.Array:
         for a in (entry if isinstance(entry, tuple) else (entry,)):
             n *= sizes.get(a, 1)
         out.append(entry if x.shape[i] % n == 0 else None)
-    return jax.lax.with_sharding_constraint(x, P(*out))
+    return jax.lax.with_sharding_constraint(x,
+                                            NamedSharding(devices, P(*out)))
 
 
 def activation_hint(x: jax.Array) -> jax.Array:
@@ -85,7 +85,7 @@ def activation_hint(x: jax.Array) -> jax.Array:
     cutting their per-device footprint by the TP degree (the difference
     between fitting and OOM for the 123B–671B train cells)."""
     mesh = active_mesh()
-    if not substrate.supports_spec_constraint(mesh) or x.ndim < 3:
+    if mesh is None or x.ndim < 3:
         return x
     auto = set(auto_axis_names(mesh))
     sizes = {k: v for k, v in dict(mesh.shape).items() if k in auto}
@@ -97,8 +97,7 @@ def activation_hint(x: jax.Array) -> jax.Array:
     s_entry = "model" if ("model" in sizes
                           and x.shape[1] % sizes["model"] == 0
                           and x.shape[1] >= 2 * sizes["model"]) else None
-    spec = P(b_entry, s_entry, *([None] * (x.ndim - 2)))
-    return jax.lax.with_sharding_constraint(x, spec)
+    return shard_hint(x, P(b_entry, s_entry, *([None] * (x.ndim - 2))))
 
 
 def tree_filter_specs(spec_tree: Any, axis_names: Sequence[str]) -> Any:

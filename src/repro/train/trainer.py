@@ -276,6 +276,10 @@ def _accumulate_grads(loss_fn: Callable, params, batch, n_micro: int,
 
     micro = _split_micro(batch, n_micro)
 
+    # jit: the in-scan and the peeled iteration are then the same compiled
+    # program — run eagerly, the peeled call would otherwise execute op by
+    # op and round differently from the compiled scan body.
+    @jax.jit
     def body(carry, mb):
         loss_acc, grads_acc = carry
         (loss, _), grads = jax.value_and_grad(
@@ -602,13 +606,11 @@ def make_train_step(model, optimizer, cfg: TrainCfg = TrainCfg(),
             rs_sched.meta["pass_us"] = rs_us
             ag_sched.meta["pass_us"] = ag_us
         # optimizer state is data-axis sharded: its specs (not P()) go
-        # into the step's shard_map so each rank holds 1/N of it.  The
-        # substrate's spec trees are leaf-wise (no subtree prefixes), so
-        # the replicated params get a per-leaf P() tree.
-        zstate_specs = {"params": jax.tree_util.tree_map(lambda _: P(),
-                                                         params_abs),
+        # into the step's shard_map so each rank holds 1/N of it.
+        zstate_specs = {"params": P(),
                         "opt": _zero_opt_specs(model, optimizer, cfg, mesh),
                         "step": P()}
+        zstate_rest = P(tuple(a for a in mesh.axis_names if a != zax))
 
     def _zero_inner(st, loss, grads):
         """The ZeRO-1 step body (runs inside the manual shard_map):
@@ -646,17 +648,18 @@ def make_train_step(model, optimizer, cfg: TrainCfg = TrainCfg(),
 
         idx = zcomm.axis_index()
         pleaves = jax.tree_util.tree_leaves(st["params"])
-        # Re-constrain the param read replicated over the auto axes: the
-        # forward's activation hints shard some leaves (embed/lm_head/
-        # mlp/final-norm) over "model", and feeding those into the
-        # pad/slice/all-gather chain unconstrained miscompiles under the
-        # legacy partitioner (see substrate._vmap_shard_map).
-        pchunks = [_zero_chunk(shard_hint(l, P()), zp, idx)
-                   for l in pleaves]
+        pchunks = [_zero_chunk(l, zp, idx) for l in pleaves]
         new_pc, new_opt, om = optimizer.update(
             jax.tree_util.tree_unflatten(gdef, chunks), st["opt"],
             jax.tree_util.tree_unflatten(gdef, pchunks),
             global_norm_fn=gnorm_fn)
+        # This rank's state chunk spreads over the remaining (auto) mesh
+        # axes too, so ZeRO divides the optimizer state by the
+        # data-parallel degree on top of the model-axis sharding the
+        # unsharded state has.
+        new_opt = jax.tree_util.tree_map(
+            lambda l: shard_hint(l, zstate_rest) if l.ndim == 1 else l,
+            new_opt)
         npc = jax.tree_util.tree_leaves(new_pc)
         fulls = [None] * len(pleaves)
 

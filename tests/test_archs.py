@@ -129,3 +129,32 @@ def test_long_500k_applicability_flags():
         fam = ARCHS[a].family
         if fam in ("ssm", "hybrid"):
             assert a in runs
+
+
+@pytest.mark.parametrize("seq", [8, 13, 21])
+def test_ssd_chunked_matches_recurrence_any_length(rng, seq):
+    """SSD in chunked form equals the token-by-token recurrence, also for
+    lengths that are not a multiple of the chunk (right-padded with
+    dt = 0 steps, which leave the state untouched)."""
+    from repro.models.mamba import ssd_chunked
+    b, h, p, n = 2, 4, 8, 16
+    x = rng.randn(b, seq, h, p).astype(np.float32)
+    dt = (np.abs(rng.randn(b, seq, h)) * 0.1).astype(np.float32)
+    a = -np.linspace(1.0, 4.0, h).astype(np.float32)
+    bm = rng.randn(b, seq, 1, n).astype(np.float32)
+    cm = rng.randn(b, seq, 1, n).astype(np.float32)
+    y, h_final = ssd_chunked(jnp.asarray(x), jnp.asarray(dt),
+                             jnp.asarray(a), jnp.asarray(bm),
+                             jnp.asarray(cm), chunk=8)
+    state = np.zeros((b, h, p, n), np.float32)
+    ys = []
+    for t in range(seq):
+        decay = np.exp(dt[:, t] * a)
+        state = (state * decay[..., None, None]
+                 + np.einsum("bhp,bn,bh->bhpn", x[:, t], bm[:, t, 0],
+                             dt[:, t]))
+        ys.append(np.einsum("bhpn,bn->bhp", state, cm[:, t, 0]))
+    np.testing.assert_allclose(np.asarray(y), np.stack(ys, 1),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h_final), state,
+                               rtol=1e-4, atol=1e-4)

@@ -3,7 +3,7 @@
 import itertools
 
 import pytest
-from _prop import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import registry
 from repro.core.compose import NotComposedError, compose

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _prop import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CollectiveEngine, EngineConfig, compose_library,
                         costmodel, layers, registry, scan_step,

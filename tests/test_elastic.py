@@ -1,10 +1,10 @@
 """Property tests for elastic mesh planning (`plan_mesh_shape`) and the
-fault-injection plan — hypothesis with the tests/_prop.py fallback."""
+fault-injection plan (hypothesis)."""
 
 import math
 
 import pytest
-from _prop import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime.controller import FaultEvent, FaultPlan
 from repro.runtime.elastic import (make_mesh_from_shape, plan_mesh_shape,

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _prop import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import ops as fops
 from repro.kernels.flash_attention import ref as fref
@@ -25,7 +25,7 @@ from repro.kernels.quantize import ref as qref
 @pytest.mark.parametrize("scale", [0.1, 10.0])
 def test_quantize_matches_ref(rng, n, scale):
     x = jnp.asarray(rng.randn(n).astype(np.float32) * scale)
-    qk, sk = qops.quantize(x, force_kernel=True)
+    qk, sk = qops.quantize(x, force_kernel=True, interpret=True)
     qr, sr = qref.quantize(x)
     np.testing.assert_array_equal(np.asarray(qk), np.asarray(qr))
     np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=1e-6)
@@ -33,8 +33,8 @@ def test_quantize_matches_ref(rng, n, scale):
 
 def test_quantize_roundtrip_error_bound(rng):
     x = jnp.asarray(rng.randn(2048).astype(np.float32))
-    q, s = qops.quantize(x, force_kernel=True)
-    y = qops.dequantize(q, s, force_kernel=True)
+    q, s = qops.quantize(x, force_kernel=True, interpret=True)
+    y = qops.dequantize(q, s, force_kernel=True, interpret=True)
     blockmax = np.abs(np.asarray(x).reshape(-1, 256)).max(1, keepdims=True)
     bound = np.repeat(blockmax / 127.0, 256, 1).reshape(-1) * 0.5 + 1e-7
     assert (np.abs(np.asarray(y) - np.asarray(x)) <= bound + 1e-6).all()
@@ -43,8 +43,8 @@ def test_quantize_roundtrip_error_bound(rng):
 def test_dequant_add_fused(rng):
     acc = jnp.asarray(rng.randn(1024).astype(np.float32))
     x = jnp.asarray(rng.randn(1024).astype(np.float32))
-    q, s = qops.quantize(x, force_kernel=True)
-    out = qops.dequant_add(acc, q, s, force_kernel=True)
+    q, s = qops.quantize(x, force_kernel=True, interpret=True)
+    out = qops.dequant_add(acc, q, s, force_kernel=True, interpret=True)
     want = qref.dequant_add(acc, q, s)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
@@ -52,7 +52,7 @@ def test_dequant_add_fused(rng):
 
 def test_quantize_zero_block():
     x = jnp.zeros((512,), jnp.float32)
-    q, s = qops.quantize(x, force_kernel=True)
+    q, s = qops.quantize(x, force_kernel=True, interpret=True)
     assert (np.asarray(q) == 0).all()
     np.testing.assert_allclose(np.asarray(s), 1.0)  # no div-by-zero
 
@@ -64,8 +64,9 @@ def test_quantize_zero_block():
 def test_prop_quantize_roundtrip(blocks, scale, dtype):
     rng = np.random.RandomState(blocks)
     x = jnp.asarray((rng.randn(blocks * 256) * scale).astype(dtype))
-    q, s = qops.quantize(x.astype(jnp.float32), force_kernel=True)
-    y = qops.dequantize(q, s, force_kernel=True)
+    q, s = qops.quantize(x.astype(jnp.float32), force_kernel=True,
+                         interpret=True)
+    y = qops.dequantize(q, s, force_kernel=True, interpret=True)
     err = np.abs(np.asarray(y) - np.asarray(x, np.float32))
     assert err.max() <= np.abs(np.asarray(x, np.float32)).max() / 100
 
@@ -77,7 +78,7 @@ def test_prop_quantize_roundtrip(blocks, scale, dtype):
 @pytest.mark.parametrize("k,n", [(2, 128), (5, 1000), (8, 4096), (3, 77)])
 def test_sum_chunks(rng, k, n):
     x = jnp.asarray(rng.randn(k, n).astype(np.float32))
-    out = lops.sum_chunks(x, force_kernel=True)
+    out = lops.sum_chunks(x, force_kernel=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(lref.sum_chunks(x)),
                                rtol=1e-5, atol=1e-5)
 
@@ -87,7 +88,7 @@ def test_sum_chunks(rng, k, n):
 def test_prop_sum_chunks(k, n):
     rng = np.random.RandomState(k * 1000 + n)
     x = jnp.asarray(rng.randn(k, n).astype(np.float32))
-    out = lops.sum_chunks(x, force_kernel=True)
+    out = lops.sum_chunks(x, force_kernel=True, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(x).sum(0), rtol=1e-4, atol=1e-4)
 
@@ -104,7 +105,7 @@ def test_flash_vs_exact(rng, causal, hq, hkv):
     k = jnp.asarray(rng.randn(B, S, hkv, D).astype(np.float32))
     v = jnp.asarray(rng.randn(B, S, hkv, D).astype(np.float32))
     outk = fops.attention(q, k, v, causal=causal, force_kernel=True,
-                          block_q=128, block_k=128)
+                          interpret=True, block_q=128, block_k=128)
     outr = fops.attention(q, k, v, causal=causal, force_kernel=False)
     np.testing.assert_allclose(np.asarray(outk), np.asarray(outr), atol=3e-5)
 
@@ -115,7 +116,8 @@ def test_flash_q_offset_decode_block(rng):
     k = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32))
     v = jnp.asarray(rng.randn(B, S, H, D).astype(np.float32))
     outk = fops.attention(q, k, v, causal=True, q_offset=128,
-                          force_kernel=True, block_q=128, block_k=128)
+                          force_kernel=True, interpret=True,
+                          block_q=128, block_k=128)
     outr = fops.attention(q, k, v, causal=True, q_offset=128,
                           force_kernel=False)
     np.testing.assert_allclose(np.asarray(outk), np.asarray(outr), atol=3e-5)
@@ -128,7 +130,7 @@ def test_flash_dtypes(rng, dtype):
     k = jnp.asarray(rng.randn(B, S, H, D)).astype(dtype)
     v = jnp.asarray(rng.randn(B, S, H, D)).astype(dtype)
     outk = fops.attention(q, k, v, causal=True, force_kernel=True,
-                          block_q=128, block_k=128)
+                          interpret=True, block_q=128, block_k=128)
     outr = fops.attention(q, k, v, causal=True, force_kernel=False)
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
     np.testing.assert_allclose(np.asarray(outk, np.float32),
@@ -148,7 +150,7 @@ def test_prop_flash_shapes(sq_blocks, skv_blocks, h):
     # causal only valid when sq <= skv (query block ends inside kv)
     causal = sq <= skv
     outk = fops.attention(q, k, v, causal=causal, force_kernel=True,
-                          block_q=blk, block_k=blk)
+                          interpret=True, block_q=blk, block_k=blk)
     outr = fops.attention(q, k, v, causal=causal, force_kernel=False)
     np.testing.assert_allclose(np.asarray(outk), np.asarray(outr), atol=3e-5)
 

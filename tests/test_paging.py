@@ -5,10 +5,6 @@ defragment) against ``PagePool.check_integrity`` prove the allocator
 never leaks or double-frees pages; separate tests pin the page-granular
 splice/extract inversion (data survives a round trip to host, including
 across a defragment) and the snapshot -> restore free-list accounting.
-
-``_prop`` is the offline hypothesis fallback: with hypothesis installed
-these are real property tests, without it they run as seeded
-fixed-example tests.
 """
 
 import random
@@ -16,7 +12,7 @@ import random
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _prop import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serve.engine import ServeCfg
 from repro.serve.paging import (OutOfPages, PagePool, RequestCache,

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _prop import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import compression
 from repro.core.protocols import bruck, pipeline, recursive, ring, tree

@@ -15,10 +15,12 @@ from repro.runtime import substrate
 
 
 def test_backend_selected_and_described():
-    assert substrate.BACKEND in ("explicit", "legacy")
-    desc = substrate.describe()
-    assert jax.__version__ in desc
-    assert substrate.BACKEND in desc
+    # one backend: JAX's explicit-axis API, meshes all-Auto by default
+    assert substrate.AxisType is jax.sharding.AxisType
+    mesh = substrate.make_mesh((1,), ("data",))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,)
+    am = substrate.abstract_mesh((2,), ("data",))
+    assert am.axis_types == (jax.sharding.AxisType.Auto,)
 
 
 def test_make_mesh_single_device():

@@ -391,13 +391,11 @@ print("OK zero elastic recovery", report.losses)
 """, timeout=600)
 
 def test_zero_matches_unsharded_on_non_pow2_dp():
-    # Regression: on a (3, 2) mesh the legacy partial-manual emulation
-    # (vmap over "data", "model" auto) miscompiled the unconstrained
-    # param->chunk->all-gather chain for leaves the forward shards over
-    # "model" (embed/lm_head/mlp/final-norm) — losses exploded after one
-    # step.  The shard_hint(..., P()) pins in _zero_inner fix it; odd
-    # per-rank chunks use plain-ring RS so equality is up to summation
-    # order here, not bitwise.
+    # Regression: on a (3, 2) mesh the param->chunk->all-gather chain
+    # for leaves the forward shards over "model" (embed/lm_head/mlp/
+    # final-norm) once miscompiled and losses exploded after one step.
+    # Odd per-rank chunks use plain-ring RS, so equality is up to
+    # summation order here, not bitwise.
     run_subprocess_script("""
 import numpy as np
 import jax

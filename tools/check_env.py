@@ -1,11 +1,10 @@
 #!/usr/bin/env python
-"""Environment probe: which JAX is installed, how many devices it sees,
-and which device-substrate backend was selected.
+"""Environment probe: which JAX is installed and which devices it sees.
 
     PYTHONPATH=src python tools/check_env.py
 
-Exit status is 0 when the substrate imported cleanly, 1 otherwise — handy
-as a CI preflight before the real test run.
+Exit status is 0 when JAX and the repro package imported cleanly, 1
+otherwise — handy as a preflight before the real test run.
 """
 
 import os
@@ -22,18 +21,15 @@ def main() -> int:
         print(f"FATAL: jax failed to import: {e}")
         return 1
     try:
-        from repro.runtime import substrate
+        import repro.runtime  # noqa: F401
     except Exception as e:
-        print(f"jax {jax.__version__} imported, but the substrate did not: "
-              f"{e}")
+        print(f"jax {jax.__version__} imported, but repro did not: {e}")
         return 1
-    print(substrate.describe())
-    try:
-        import hypothesis  # noqa: F401
-        print("hypothesis:        installed (property tests full)")
-    except ImportError:
-        print("hypothesis:        absent (tests/_prop.py fixed-example "
-              "fallback)")
+    devices = jax.devices()
+    print(f"jax version:  {jax.__version__}")
+    print(f"platform:     {devices[0].platform}")
+    print(f"device_kind:  {devices[0].device_kind}")
+    print(f"device count: {len(devices)}")
     return 0
 
 
