@@ -40,18 +40,30 @@ index, admission order, and prefill chunking (chunked vs one-shot is
 bit-identical), which is what makes tokens bit-identical across an
 elastic re-mesh (same contract the training tier proves in
 tests/test_controller.py).
+
+Tracing: the scheduler marks its phases with ``jax.profiler`` spans, so
+that a profiler trace puts them on the device trace's clock.  They are
+always on (a span costs a microsecond or two while no trace runs) and
+nest: ``serve.step`` (``step_num``) holds ``serve.admit``,
+``serve.prefill_chunk`` (``rid``, ``chunk``), ``serve.decode``
+(``slots``: the slots decoding in that program) and ``serve.sample``
+(token readback and the slot bookkeeping after it; ``rid`` after a
+prompt's last chunk); the pool's ``serve.pages`` sits inside a chunk or
+a decode.  ``serve.admit`` also runs inside ``submit()``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.serve import paging
 from repro.serve.paging import (OutOfPages, PagePool, RequestCache,
@@ -273,6 +285,7 @@ class BatchScheduler:
     def _n_chunks(self, req: Request) -> int:
         return -(-len(req.prompt) // self.pool.page_tokens)
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.admit")
     def _admit(self) -> None:
         for i, slot in enumerate(self.slots):
             if slot is not None:
@@ -378,24 +391,34 @@ class BatchScheduler:
         chunk, samples the first token and flips the slot to decoding."""
         pf = self._prefills[i]
         req = pf.req
-        pt = self.pool.page_tokens
-        c = pf.chunks_done
-        valid_len = min((c + 1) * pt, len(req.prompt))
-        try:
-            self.pool.ensure(req.rid, valid_len)
-        except OutOfPages:
-            return False
-        chunk = req.prompt[c * pt:(c + 1) * pt]
-        chunk = list(chunk) + [0] * (pt - len(chunk))   # pad to the page
-        last_index = (len(req.prompt) - 1) - c * pt     # final-chunk only
-        logits, pf.state = self._chunk(
-            self.params, req.rid, jnp.asarray(chunk, jnp.int32)[None, :],
-            c, valid_len, max(0, min(last_index, pt - 1)), pf.state)
-        pf.chunks_done += 1
-        if pf.chunks_done < self._n_chunks(req):
+        with TraceAnnotation("serve.prefill_chunk", rid=req.rid,
+                             chunk=pf.chunks_done):
+            pt = self.pool.page_tokens
+            c = pf.chunks_done
+            valid_len = min((c + 1) * pt, len(req.prompt))
+            try:
+                self.pool.ensure(req.rid, valid_len)
+            except OutOfPages:
+                return False
+            chunk = req.prompt[c * pt:(c + 1) * pt]
+            chunk = list(chunk) + [0] * (pt - len(chunk))   # pad to the page
+            last_index = (len(req.prompt) - 1) - c * pt     # final-chunk only
+            logits, pf.state = self._chunk(
+                self.params, req.rid, jnp.asarray(chunk, jnp.int32)[None, :],
+                c, valid_len, max(0, min(last_index, pt - 1)), pf.state)
+            pf.chunks_done += 1
+            if pf.chunks_done < self._n_chunks(req):
+                return True
+            with TraceAnnotation("serve.sample", rid=req.rid):
+                self._finish_prefill(i, pf, logits)
             return True
-        # Prefill complete: first token is sampled at (rid, pos=0) —
-        # identical whether the chunks ran interleaved or back-to-back.
+
+    def _finish_prefill(self, i: int, pf: _Prefill, logits) -> None:
+        """Prefill complete: the first token is sampled at (rid, pos=0) —
+        identical whether the chunks ran interleaved or back-to-back —
+        and the slot flips to decoding (or frees, if that token ends the
+        request)."""
+        req = pf.req
         rid1 = jnp.asarray([req.rid], jnp.int32)
         tok = int(_pick_tokens(logits, self.cfg, rid1,
                                jnp.zeros_like(rid1))[0])
@@ -409,11 +432,10 @@ class BatchScheduler:
             self.slots[i] = None
             self.pool.release(req.rid)
             self._admit_seq.pop(req.rid, None)
-            return True
+            return
         self._next_tok = self._next_tok.at[i].set(tok)
         self._rids = self._rids.at[i].set(req.rid)
         self._pos = self._pos.at[i].set(1)
-        return True
 
     # -- preemption --------------------------------------------------------
 
@@ -457,6 +479,10 @@ class BatchScheduler:
         """Admit + advance prefill chunks + one fused decode step for all
         decoding slots (under the comm session's mesh when one was
         given).  Returns the number of in-flight requests touched."""
+        with StepTraceAnnotation("serve.step", step_num=self.decode_steps):
+            return self._step()
+
+    def _step(self) -> int:
         with _mesh_scope(self.comm):
             before = set(self._prefills)
             n_done = len(self.completed)
@@ -483,26 +509,29 @@ class BatchScheduler:
                         "and nothing is decoding to free pages; raise "
                         "pool_pages")
                 return prefilling
-            active = self._ensure_decode_pages(active)
-            mask = [False] * self.cfg.batch
+            with TraceAnnotation("serve.decode") as span:
+                active = self._ensure_decode_pages(active)
+                span.set_metadata(slots=len(active))
+                mask = [False] * self.cfg.batch
+                for i in active:
+                    mask[i] = True
+                slot_rids = [s.rid if s is not None and mask[j] else None
+                             for j, s in enumerate(self.slots)]
+                nxt = self._decode(self.params, self._next_tok[:, None],
+                                   self._rids, self._pos, slot_rids, mask)
+                self._pos = self._pos + 1
+        with TraceAnnotation("serve.sample"):
+            self._next_tok = nxt
+            self.decode_steps += 1
             for i in active:
-                mask[i] = True
-            slot_rids = [s.rid if s is not None and mask[j] else None
-                         for j, s in enumerate(self.slots)]
-            nxt = self._decode(self.params, self._next_tok[:, None],
-                               self._rids, self._pos, slot_rids, mask)
-            self._pos = self._pos + 1
-        self._next_tok = nxt
-        self.decode_steps += 1
-        for i in active:
-            req = self.slots[i]
-            req.generated.append(int(nxt[i]))
-            if req.done or (self.cfg.eos_id >= 0
-                            and req.generated[-1] == self.cfg.eos_id):
-                self.completed.append(req)
-                self.slots[i] = None
-                self.pool.release(req.rid)
-                self._admit_seq.pop(req.rid, None)
+                req = self.slots[i]
+                req.generated.append(int(nxt[i]))
+                if req.done or (self.cfg.eos_id >= 0
+                                and req.generated[-1] == self.cfg.eos_id):
+                    self.completed.append(req)
+                    self.slots[i] = None
+                    self.pool.release(req.rid)
+                    self._admit_seq.pop(req.rid, None)
         return len(active) + prefilling
 
     def pending(self) -> bool:

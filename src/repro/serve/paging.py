@@ -47,6 +47,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class OutOfPages(RuntimeError):
@@ -474,13 +475,16 @@ class PagePool:
     def bind_decode(self, decode_fn) -> Callable:
         """One jitted paged decode step: assemble arena from pages ->
         ``decode_fn`` -> write each active slot's touched page back.
-        Returns ``fn(params, tok, rids, pos, table, pids, ks, active)``
-        -> next tokens (and commits pool/state internally)."""
+        Returns ``fn(params, tok, rids, pos, slot_rids, active_mask)``
+        -> next tokens (and commits pool/state internally).  The program
+        is ``paged_decode`` (``jit_paged_decode`` in a profiler trace);
+        building and uploading its page arguments is the ``serve.pages``
+        span."""
         b = self.cfg.batch
 
         @jax.jit
-        def step(params, pool, state, tok, rids, pos, table, pids, ks,
-                 active):
+        def paged_decode(params, pool, state, tok, rids, pos, table, pids,
+                         ks, active):
             caches = self._assemble(pool, state, table)
             nxt, new_caches = decode_fn(params, tok, caches, rids, pos)
             tok_leaves, new_state = self._split(new_caches)
@@ -503,21 +507,23 @@ class PagePool:
             return nxt, pool, out_state
 
         def run(params, tok, rids, pos, slot_rids, active_mask):
-            table = self.table_array(slot_rids)
-            pt = self.page_tokens
-            pids, ks = [], []
-            for r, a in zip(slot_rids, active_mask):
-                t = self.tables.get(r) if r is not None else None
-                if a and t is not None:
-                    pids.append(t.page_of(t.tokens, pt))
-                    ks.append(t.tokens // pt)
-                else:
-                    pids.append(0)
-                    ks.append(0)
-            nxt, self.pool, self.state = step(
-                params, self.pool, self.state, tok, rids, pos, table,
-                jnp.asarray(pids, jnp.int32), jnp.asarray(ks, jnp.int32),
-                jnp.asarray(active_mask, jnp.bool_))
+            with TraceAnnotation("serve.pages"):
+                table = self.table_array(slot_rids)
+                pt = self.page_tokens
+                pids, ks = [], []
+                for r, a in zip(slot_rids, active_mask):
+                    t = self.tables.get(r) if r is not None else None
+                    if a and t is not None:
+                        pids.append(t.page_of(t.tokens, pt))
+                        ks.append(t.tokens // pt)
+                    else:
+                        pids.append(0)
+                        ks.append(0)
+                args = (table, jnp.asarray(pids, jnp.int32),
+                        jnp.asarray(ks, jnp.int32),
+                        jnp.asarray(active_mask, jnp.bool_))
+            nxt, self.pool, self.state = paged_decode(
+                params, self.pool, self.state, tok, rids, pos, *args)
             for r, a in zip(slot_rids, active_mask):
                 if a and r is not None:
                     self.tables[r].tokens += 1
@@ -530,11 +536,12 @@ class PagePool:
         request's pages: ``chunk_fn(params, tokens, caches, q_offset,
         valid_len, last_index)`` -> (logits, caches).  Writes the chunk's
         page back and returns (logits, state-leaves) for the caller to
-        carry between chunks."""
+        carry between chunks.  The program is ``paged_prefill_chunk``;
+        its page arguments are built in a ``serve.pages`` span (``rid``)."""
 
         @jax.jit
-        def step(params, pool, state1, tokens, table1, q_offset, valid_len,
-                 last_index, pid, k):
+        def paged_prefill_chunk(params, pool, state1, tokens, table1,
+                                q_offset, valid_len, last_index, pid, k):
             caches = self._assemble(pool, state1, table1)
             logits, new_caches = chunk_fn(params, tokens, caches, q_offset,
                                           valid_len, last_index)
@@ -545,13 +552,14 @@ class PagePool:
 
         def run(params, rid, tokens, chunk_idx, valid_len, last_index,
                 state1):
-            table1 = self.table_array([rid])
-            t = self.tables[rid]
-            logits, self.pool, new_state = step(
-                params, self.pool, state1, tokens, table1,
-                jnp.int32(chunk_idx * self.page_tokens),
-                jnp.int32(valid_len), jnp.int32(last_index),
-                jnp.int32(t.pages[chunk_idx]), jnp.int32(chunk_idx))
+            with TraceAnnotation("serve.pages", rid=rid):
+                t = self.tables[rid]
+                args = (self.table_array([rid]),
+                        jnp.int32(chunk_idx * self.page_tokens),
+                        jnp.int32(valid_len), jnp.int32(last_index),
+                        jnp.int32(t.pages[chunk_idx]), jnp.int32(chunk_idx))
+            logits, self.pool, new_state = paged_prefill_chunk(
+                params, self.pool, state1, tokens, *args)
             t.tokens = min(valid_len, (chunk_idx + 1) * self.page_tokens)
             return logits, new_state
 
