@@ -6,4 +6,4 @@ Each kernel subpackage follows the pattern:
   ref.py    — pure-jnp oracle used by tests and as the CPU fallback
 """
 
-__all__ = ["flash_attention", "local_reduce", "quantize"]
+__all__ = ["flash_attention", "local_reduce", "paged_attention", "quantize"]

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.paged_attention import ops as paged_ops
+
 Params = Dict[str, Any]
 
 # ---------------------------------------------------------------------------
@@ -331,7 +333,10 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """Single-token attention against a (possibly ragged) cache.
 
     q: (B, 1, H, D); caches: (B, Smax, Hkv, D); kv_len: (B,) valid lengths.
-    Memory-bound matvec — runs as plain jnp (no kernel needed).
+    Reads all ``Smax`` positions and masks afterwards: the path of the
+    contiguous caches (``generate``) and of the page pool's arena program
+    (models whose cache is not all GQA K/V).  The pool's GQA decode reads
+    only the live pages instead (``attention_decode_paged``).
     """
     b, _, h, d = q.shape
     _, smax, hkv, _ = k_cache.shape
@@ -490,6 +495,38 @@ def attention_decode(params: Params, cfg: AttentionCfg, x: jax.Array,
     out = decode_attention(q, kc, vc, new_len)
     out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim)
     return out @ params["wo"], {"k": kc, "v": vc, "len": new_len}
+
+
+def attention_decode_paged(params: Params, cfg: AttentionCfg, x: jax.Array,
+                           cache: Dict[str, jax.Array], layer, table, active
+                           ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One-token decode against the page pool, with no arena.
+
+    x: (B, 1, D); cache: this layer's "k"/"v" pool leaves, ``(num_pages +
+    1, page_tokens, L, Hkv, Dh)`` read at ``layer``, and "len" (B,), the
+    positions each slot holds; table: (B, pages_per_slot) page ids;
+    active: (B,) bool, the slots that decode.  Attention reads each active
+    slot's live pages (``repro.kernels.paged_attention``) with the new
+    token's own k/v folded in.  Nothing is written here: the returned
+    cache holds the new token's "k"/"v" (B, Hkv, Dh) and the advanced
+    "len", and the page pool writes every layer's token into its page.
+    """
+    b = x.shape[0]
+    q, k, v = _project_qkv(params, cfg, x)
+    pos = cache["len"][:, None]                           # (B, 1)
+    if cfg.mrope_sections is not None:
+        pos = jnp.broadcast_to(pos, (3,) + pos.shape)
+    cos, sin = _rope_for(cfg, pos, b, 1)
+    q = apply_rope(q, cos, sin)
+    k = apply_rope(k, cos, sin)
+    k_tok = k[:, 0].astype(cache["k"].dtype)
+    v_tok = v[:, 0].astype(cache["v"].dtype)
+    lengths = jnp.where(active, cache["len"] + 1, 0)
+    out = paged_ops.paged_attention(q[:, 0], cache["k"], cache["v"], layer,
+                                    lengths, table, k_tok, v_tok)
+    out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim)
+    new_len = cache["len"] + active.astype(cache["len"].dtype)
+    return out @ params["wo"], {"k": k_tok, "v": v_tok, "len": new_len}
 
 
 def _scatter_token(cache: jax.Array, token: jax.Array, idx: jax.Array

@@ -130,6 +130,22 @@ class Model:
         logits = T._unembed(params, self.cfg, h)
         return logits[:, 0], new_caches
 
+    def decode_step_paged(self, params: Params, batch: Dict[str, jax.Array],
+                          caches: Params, table: jax.Array, active: jax.Array
+                          ) -> Tuple[jax.Array, Params]:
+        """One token for every slot, K/V read from the page pool
+        (``T.decode_paged``; the pool runs it only where every cache leaf
+        is a GQA K/V or its counter).  ``caches``: the cache tree with the
+        pool's "k"/"v" leaves; ``table`` (B, pages_per_slot) page ids;
+        ``active`` (B,) bool.  Returns (logits, caches holding each
+        layer's new-token K/V and the advanced counters)."""
+        if self.kind == "encdec":
+            raise ValueError("the encoder-decoder has no paged decode")
+        h, new_caches = T.decode_paged(params, self.cfg, batch, caches,
+                                       table, active)
+        logits = T._unembed(params, self.cfg, h)
+        return logits[:, 0], new_caches
+
 
 def _specs_decoder(cfg: T.TransformerCfg) -> Params:
     return _eval_specs(lambda k: T.init_params(k, cfg))

@@ -130,15 +130,20 @@ def _mixer_cache_specs(cfg: TransformerCfg, spec: LayerSpec):
 def apply_layer(params: Params, cfg: TransformerCfg, spec: LayerSpec,
                 x: jax.Array, *, positions=None, q_offset=0,
                 cache: Optional[Params] = None, decode: bool = False,
-                chunked: bool = False, valid_len=None
+                chunked: bool = False, valid_len=None, pages=None
                 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Returns (x_out, new_cache, aux_loss)."""
+    """Returns (x_out, new_cache, aux_loss).  ``pages``: (layer, table,
+    active) of a paged decode (``decode_paged``), where ``cache`` holds
+    the pool's leaves."""
     aux = jnp.zeros((), jnp.float32)
     h = _norm(cfg, params["norm_mixer"], x)
     h = shard_hint(h, P(("pod", "data"), None, None))
     new_cache = None
     if spec.mixer == "attn":
-        if decode:
+        if pages is not None:
+            out, new_cache = L.attention_decode_paged(
+                params["attn"], cfg.attn, h, cache, *pages)
+        elif decode:
             out, new_cache = L.attention_decode(
                 params["attn"], cfg.attn, h, cache, positions=positions)
         else:
@@ -304,6 +309,55 @@ def forward(params: Params, cfg: TransformerCfg, batch: Dict[str, jax.Array],
         aux_total = aux_total + aux
     h = _norm(cfg, params["final_norm"], h)
     return h, new_caches, aux_total
+
+
+def _decode_stage_paged(params_stage: Params, cfg: TransformerCfg,
+                        stage: StageSpec, x: jax.Array, caches: Params,
+                        table: jax.Array, active: jax.Array
+                        ) -> Tuple[jax.Array, Params]:
+    """One decode token through a stage of GQA layers whose K/V live in
+    the page pool.  ``caches[f"layer{j}"]``: "k"/"v" pool leaves
+    ``(num_pages + 1, page_tokens, repeat, Hkv, Dh)`` and "len" (repeat,
+    B).  The pool leaves are scan constants read at the layer counter (no
+    per-layer slice is made).  Returns the stage's output and, per layer,
+    the new token's "k"/"v" (repeat, B, Hkv, Dh) and the advanced
+    "len"."""
+    names = [f"layer{j}" for j in range(len(stage.layers))]
+
+    def body(x, xs):
+        layer_params, lens, layer = xs
+        out = {}
+        for name, spec in zip(names, stage.layers):
+            cache = {"k": caches[name]["k"], "v": caches[name]["v"],
+                     "len": lens[name]}
+            x, out[name], _ = apply_layer(
+                layer_params[name], cfg, spec, x, cache=cache, decode=True,
+                pages=(layer, table, active))
+        return x, out
+
+    return jax.lax.scan(
+        body, x, (params_stage, {n: caches[n]["len"] for n in names},
+                  jnp.arange(stage.repeat)))
+
+
+def decode_paged(params: Params, cfg: TransformerCfg,
+                 batch: Dict[str, jax.Array], caches: Params,
+                 table: jax.Array, active: jax.Array
+                 ) -> Tuple[jax.Array, Params]:
+    """One decode token per slot of an all-GQA model whose K/V live in the
+    page pool (``repro.serve.paging``).  ``caches`` mirrors
+    ``init_caches``' tree with each "k"/"v" the pool leaf and each "len"
+    the slot counters; ``table`` (B, pages_per_slot) page ids; ``active``
+    (B,) bool.  Returns (hidden (B, 1, D), the same tree with each "k"/"v"
+    the new token's (layers, B, Hkv, Dh) and each "len" advanced for the
+    active slots); the pool writes the tokens into their pages."""
+    h = _embed(params, cfg, batch)
+    new_caches = {}
+    for i, stage in enumerate(cfg.stages):
+        h, new_caches[f"stage{i}"] = _decode_stage_paged(
+            params[f"stage{i}"], cfg, stage, h, caches[f"stage{i}"], table,
+            active)
+    return _norm(cfg, params["final_norm"], h), new_caches
 
 
 def logits_fn(params: Params, cfg: TransformerCfg,

@@ -46,7 +46,9 @@ that a profiler trace puts them on the device trace's clock.  They are
 always on (a span costs a microsecond or two while no trace runs) and
 nest: ``serve.step`` (``step_num``) holds ``serve.admit``,
 ``serve.prefill_chunk`` (``rid``, ``chunk``), ``serve.decode``
-(``slots``: the slots decoding in that program) and ``serve.sample``
+(``slots``: the slots decoding in that program; ``paged``: 1 where the
+paged decode ran, 0 for the arena program; ``kv_pages``: the KV pages
+its attention reads, from the host's books) and ``serve.sample``
 (token readback and the slot bookkeeping after it; ``rid`` after a
 prompt's last chunk); the pool's ``serve.pages`` sits inside a chunk or
 a decode.  ``serve.admit`` also runs inside ``submit()``.
@@ -130,6 +132,17 @@ def make_decode_step(model, cfg: ServeCfg) -> Callable:
         away) on the greedy path."""
         logits, caches = model.decode_step(params, {"tokens": tokens},
                                            caches)
+        return _pick_tokens(logits, cfg, rids, pos), caches
+    return decode_step
+
+
+def make_paged_decode_step(model, cfg: ServeCfg) -> Callable:
+    def decode_step(params, tokens, caches, table, active, rids, pos):
+        """``decode_step`` on the page pool's own K/V leaves
+        (``Model.decode_step_paged``): ``table`` (B, pages_per_slot) page
+        ids, ``active`` (B,) the slots that decode."""
+        logits, caches = model.decode_step_paged(
+            params, {"tokens": tokens}, caches, table, active)
         return _pick_tokens(logits, cfg, rids, pos), caches
     return decode_step
 
@@ -219,8 +232,12 @@ class BatchScheduler:
     ``page_tokens`` chunk per ``step()`` interleaved with decode — a long
     prompt never stalls the batch; other models prefill one-shot on a
     contiguous batch-1 row that the pool then adopts page by page
-    (``splice_row``).  Decode runs one fused step for all slots over an
-    arena gathered from the pool inside the jit.
+    (``splice_row``).  Decode runs one fused step for all slots: on the
+    pool's pages directly where the cache is all GQA K/V and, on TPU,
+    the paged-attention kernel takes it on one device (only live pages
+    are read, the new token is written in place), over a ``(batch,
+    max_len)`` arena gathered inside the jit otherwise
+    (``PagePool.bind_decode``, ``paging.decodes_paged``).
 
     If decode outgrows the pool (overcommitted ``pool_pages``), the most
     recently admitted active slot is preempted — parked page-granular to
@@ -244,7 +261,8 @@ class BatchScheduler:
         self.slots: List[Optional[Request]] = [None] * cfg.batch
         with _mesh_scope(comm):
             self.pool = PagePool(model, cfg, comm=comm)
-        self._decode = self.pool.bind_decode(make_decode_step(model, cfg))
+        self._decode = self.pool.bind_decode(
+            make_decode_step(model, cfg), make_paged_decode_step(model, cfg))
         self._chunkable = bool(getattr(model, "supports_chunked_prefill",
                                        False))
         self._chunk = self.pool.bind_prefill_chunk(
@@ -511,7 +529,10 @@ class BatchScheduler:
                 return prefilling
             with TraceAnnotation("serve.decode") as span:
                 active = self._ensure_decode_pages(active)
-                span.set_metadata(slots=len(active))
+                span.set_metadata(
+                    slots=len(active), paged=int(self.pool.paged),
+                    kv_pages=self.pool.decode_kv_pages(
+                        [self.slots[i].rid for i in active]))
                 mask = [False] * self.cfg.batch
                 for i in active:
                     mask[i] = True

@@ -27,11 +27,27 @@ batch, then the max_len argument) to classify every leaf:
 
 Per request, a ``PageTable`` maps logical token positions to physical
 pages (``pages[i]`` backs positions ``[i*page_tokens, (i+1)*page_tokens)``)
-plus the logical token count.  The decode/prefill arenas the model
-actually computes on are *assembled inside the jitted step* (gather by
-page id) and the touched page is scattered back — persistent device
-memory is the pool itself, proportional to allocated pages, i.e. to
-generated length, not to ``batch * max_len``.
+plus the logical token count.  Persistent device memory is the pool
+itself, proportional to allocated pages, i.e. to generated length, not
+to ``batch * max_len``.
+
+Programs
+--------
+- **Paged decode** (``decodes_paged``: every token leaf is the K or V
+  of a GQA attention layer and every state leaf its length counter; on
+  TPU also the Pallas kernel takes the pool's layout and the decode
+  runs on one device).  The model attends to the pool's pages directly,
+  reading only each slot's live pages (``repro.kernels.paged_attention``)
+  and returning each layer's new token, which the program writes into
+  its page; the pool and the state arena are donated, so that write is
+  in place and no second pool is ever live.
+- **Arena decode** (every other case: MLA latents, recurrent state,
+  cross-attention memory, and on TPU a GQA layout the kernel cannot
+  copy or a decode over a mesh).  A ``(batch, max_len)`` arena is
+  assembled inside the jitted step (gather by page id), the model
+  decodes on it, and each slot's touched page is scattered back.
+- **Prefill chunk**: a batch-1 arena gathered from the request's pages,
+  its chunk's page scattered back.
 
 Degenerate layout: ``page_tokens == max_len`` IS the old contiguous
 layout (one page per slot), so the pool serves both and the serve bench
@@ -41,6 +57,7 @@ can compare them like-for-like.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -48,6 +65,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+
+from repro.kernels.paged_attention import kernel as paged_kernel
 
 
 class OutOfPages(RuntimeError):
@@ -154,6 +173,7 @@ class LeafLayout:
     dtype: Any
     batch_axis: int
     token_axis: Optional[int]      # None: state leaf (no position axis)
+    name: str = ""                 # the leaf's key in its cache dict
 
 
 def _diff_axes(a, b) -> List[int]:
@@ -183,6 +203,19 @@ class PageLayout:
         return [i for i, l in enumerate(self.leaves)
                 if l.token_axis is None]
 
+    @property
+    def kv_pages(self) -> bool:
+        """Whether the cache suits the paged decode: every token leaf is
+        the "k" or "v" of a GQA attention cache laid out ``(layers,
+        batch, max_len, Hkv, Dh)`` and every state leaf is a "len"
+        counter."""
+        def kv(l):
+            return (l.name in ("k", "v") and len(l.shape) == 5
+                    and (l.batch_axis, l.token_axis) == (1, 2))
+        return bool(self.token_leaf_ids) and all(
+            kv(l) if l.token_axis is not None else l.name == "len"
+            for l in self.leaves)
+
     def page_bytes(self) -> int:
         """Bytes one logical page occupies across every token leaf."""
         total = 0
@@ -207,11 +240,11 @@ def probe_layout(model, max_len: int, page_tokens: int, *,
     base = abstract_caches(model, 1, max_len, dtype=dtype)
     wide = abstract_caches(model, 2, max_len, dtype=dtype)
     deep = abstract_caches(model, 1, 2 * max_len, dtype=dtype)
-    bl, treedef = jax.tree_util.tree_flatten(base)
+    paths, treedef = jax.tree_util.tree_flatten_with_path(base)
     wl = jax.tree_util.tree_leaves(wide)
     dl = jax.tree_util.tree_leaves(deep)
     leaves = []
-    for b, w, d in zip(bl, wl, dl):
+    for (path, b), w, d in zip(paths, wl, dl):
         baxes = _diff_axes(b, w)
         if len(baxes) != 1:
             raise ValueError(
@@ -220,11 +253,31 @@ def probe_layout(model, max_len: int, page_tokens: int, *,
         if len(taxes) > 1:
             raise ValueError(
                 f"cache leaf {b.shape} has no unique token axis ({taxes})")
+        key = path[-1] if path else None
         leaves.append(LeafLayout(
             shape=tuple(b.shape), dtype=b.dtype, batch_axis=baxes[0],
-            token_axis=taxes[0] if taxes else None))
+            token_axis=taxes[0] if taxes else None,
+            name=str(getattr(key, "key", key))))
     return PageLayout(treedef=treedef, leaves=leaves, max_len=max_len,
                       page_tokens=page_tokens)
+
+
+def decodes_paged(layout: PageLayout, devices: int = 1) -> bool:
+    """Whether the pool decodes on its pages (else on a gathered arena).
+
+    The cache must be all GQA K/V (``layout.kv_pages``).  On TPU the
+    paged decode's attention is the Pallas kernel, never the jnp oracle,
+    so it runs only where the kernel can copy the pool's pages
+    (``kernel.fits``) and the decode program spans one device: a Mosaic
+    kernel is not partitioned across a mesh.  On other backends the
+    oracle attends, on any mesh.  ``devices``: the devices of the mesh
+    the decode runs under."""
+    if not layout.kv_pages:
+        return False
+    if jax.default_backend() != "tpu":
+        return True
+    return devices == 1 and all(paged_kernel.fits(layout.leaves[i])
+                                for i in layout.token_leaf_ids)
 
 
 # ---------------------------------------------------------------------------
@@ -309,6 +362,10 @@ class PagePool:
             else cfg.batch * pps
         if self.num_pages < 1:
             raise ValueError("pool needs at least one page")
+        mesh = comm.mesh if comm is not None else None
+        # which decode program runs
+        self.paged = decodes_paged(self.layout,
+                                   mesh.size if mesh is not None else 1)
         # page 0 is the reserved zero page; allocatable ids are 1..num_pages
         self._free: List[int] = list(range(self.num_pages, 0, -1))
         self.tables: Dict[int, PageTable] = {}
@@ -422,6 +479,15 @@ class PagePool:
         return jnp.asarray([self._table_row(r) for r in slot_rids],
                            jnp.int32)
 
+    def decode_kv_pages(self, rids: Sequence[int]) -> int:
+        """Pages the next decode's attention reads for the decoding
+        requests ``rids``: their live pages on the paged path, the whole
+        arena (batch x pages per slot) on the arena path.  Host books
+        only, no device sync."""
+        if not self.paged:
+            return self.cfg.batch * self.layout.pages_per_slot
+        return sum(self.pages_for(self.tables[r].tokens) for r in rids)
+
     # -- jitted assemble / writeback ---------------------------------------
 
     def _assemble(self, pool, state, table):
@@ -472,14 +538,89 @@ class PagePool:
                 jnp.where(active, page.astype(cur.dtype), cur)))
         return out
 
-    def bind_decode(self, decode_fn) -> Callable:
-        """One jitted paged decode step: assemble arena from pages ->
-        ``decode_fn`` -> write each active slot's touched page back.
-        Returns ``fn(params, tok, rids, pos, slot_rids, active_mask)``
-        -> next tokens (and commits pool/state internally).  The program
-        is ``paged_decode`` (``jit_paged_decode`` in a profiler trace);
-        building and uploading its page arguments is the ``serve.pages``
-        span."""
+    def bind_decode(self, decode_fn, paged_fn) -> Callable:
+        """One jitted decode step for every slot.  Returns ``fn(params,
+        tok, rids, pos, slot_rids, active_mask)`` -> next tokens (and
+        commits pool/state internally).  Which program it runs is fixed
+        by the probed layout (``self.paged``); both are named
+        ``paged_decode`` (``jit_paged_decode`` in a profiler trace), and
+        building and uploading their page arguments is the
+        ``serve.pages`` span.
+
+        - Paged: ``paged_fn(params, tok, caches, table, active, rids,
+          pos)`` on a cache tree whose "k"/"v" leaves are the pool's own
+          (``Model.decode_step_paged``) reads the live pages and returns
+          each layer's new token, which one scatter per leaf writes into
+          its page, in place (the pool and the state arena are donated).
+        - Arena: assemble a ``(batch, max_len)`` arena from pages ->
+          ``decode_fn(params, tok, caches, rids, pos)`` -> write each
+          active slot's touched page back.
+
+        The returned function's ``program`` is the jitted program, whose
+        arguments are ``(params, pool, state, tok, rids, pos, table,
+        pids, idx, active)``: ``idx`` (B,) is the new token's page of its
+        slot (arena) or its offset in its page (paged)."""
+        if self.paged:
+            return self._bind_paged_decode(paged_fn)
+        return self._bind_arena_decode(decode_fn)
+
+    def _paged_view(self, pool, state):
+        """The cache tree with the pool's token leaves and the state
+        arena's leaves in their places (no gather: the leaves as they
+        are)."""
+        tok, st = iter(pool), iter(state)
+        leaves = [next(tok) if l.token_axis is not None else next(st)
+                  for l in self.layout.leaves]
+        return jax.tree_util.tree_unflatten(self.layout.treedef, leaves)
+
+    def _token_pages(self, slot_rids, active_mask, idle_pid: int):
+        """Host books -> (page id, position) of each slot's next token;
+        ``(idle_pid, 0)`` for a slot that does not decode."""
+        pids, positions = [], []
+        for r, a in zip(slot_rids, active_mask):
+            t = self.tables.get(r) if r is not None else None
+            if a and t is not None:
+                pids.append(t.page_of(t.tokens, self.page_tokens))
+                positions.append(t.tokens)
+            else:
+                pids.append(idle_pid)
+                positions.append(0)
+        return pids, positions
+
+    def _bind_paged_decode(self, paged_fn) -> Callable:
+        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        def paged_decode(params, pool, state, tok, rids, pos, table, pids,
+                         offs, active):
+            nxt, new = paged_fn(params, tok, self._paged_view(pool, state),
+                                table, active, rids, pos)
+            toks, new_state = self._split(new)
+            # each leaf's token (L, B, Hkv, Dh) into row offs of page pids
+            # (an idle slot's pid is past the pool: its write is dropped)
+            pool = [p.at[pids, offs].set(
+                jnp.moveaxis(t, 1, 0).astype(p.dtype), mode="drop")
+                for p, t in zip(pool, toks)]
+            return nxt, pool, new_state
+
+        def run(params, tok, rids, pos, slot_rids, active_mask):
+            with TraceAnnotation("serve.pages"):
+                pids, positions = self._token_pages(
+                    slot_rids, active_mask, self.num_pages + 1)
+                args = (self.table_array(slot_rids),
+                        jnp.asarray(pids, jnp.int32),
+                        jnp.asarray([n % self.page_tokens
+                                     for n in positions], jnp.int32),
+                        jnp.asarray(active_mask, jnp.bool_))
+            nxt, self.pool, self.state = paged_decode(
+                params, self.pool, self.state, tok, rids, pos, *args)
+            for r, a in zip(slot_rids, active_mask):
+                if a and r is not None:
+                    self.tables[r].tokens += 1
+            return nxt
+
+        run.program = paged_decode
+        return run
+
+    def _bind_arena_decode(self, decode_fn) -> Callable:
         b = self.cfg.batch
 
         @jax.jit
@@ -508,19 +649,12 @@ class PagePool:
 
         def run(params, tok, rids, pos, slot_rids, active_mask):
             with TraceAnnotation("serve.pages"):
-                table = self.table_array(slot_rids)
-                pt = self.page_tokens
-                pids, ks = [], []
-                for r, a in zip(slot_rids, active_mask):
-                    t = self.tables.get(r) if r is not None else None
-                    if a and t is not None:
-                        pids.append(t.page_of(t.tokens, pt))
-                        ks.append(t.tokens // pt)
-                    else:
-                        pids.append(0)
-                        ks.append(0)
-                args = (table, jnp.asarray(pids, jnp.int32),
-                        jnp.asarray(ks, jnp.int32),
+                pids, positions = self._token_pages(slot_rids, active_mask,
+                                                    0)
+                args = (self.table_array(slot_rids),
+                        jnp.asarray(pids, jnp.int32),
+                        jnp.asarray([n // self.page_tokens
+                                     for n in positions], jnp.int32),
                         jnp.asarray(active_mask, jnp.bool_))
             nxt, self.pool, self.state = paged_decode(
                 params, self.pool, self.state, tok, rids, pos, *args)
@@ -528,6 +662,8 @@ class PagePool:
                 if a and r is not None:
                     self.tables[r].tokens += 1
             return nxt
+
+        run.program = paged_decode
 
         return run
 

@@ -490,3 +490,210 @@ def test_from_snapshot_sheds_queue_tail_under_max_queue():
     assert sorted(r.rid for r in done) == [0, 1, 2, 3, 4]
     for r in done:
         assert r.generated == _expected_cache_lm(r.prompt, r.max_new)
+
+
+# ---------------------------------------------------------------------------
+# Paged decode: a GQA model attends to the pool's pages directly
+# ---------------------------------------------------------------------------
+
+GQA_PROMPTS = [[1, 2, 3], [4] * 9, [5] * 5, [7] * 2, [9] * 13,
+               list(range(20))]
+
+
+class Spans:
+    """Stands in for ``TraceAnnotation`` in the engine: records each
+    span's name and metadata, keyword and ``set_metadata`` alike."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **meta):
+        self.log.append((name, meta))
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **meta):
+        self.log[-1][1].update(meta)
+
+    def named(self, name):
+        return [m for n, m in self.log if n == name]
+
+
+def _tiny(arch="qwen2-72b"):
+    from repro.configs import get_config
+    from repro.models import build_model
+    model = build_model(get_config(arch, reduced=True))
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def _gqa_sched(model, params, arena=False, monkeypatch=None):
+    from repro.serve import paging
+    cfg = ServeCfg(max_len=32, batch=3, cache_dtype=jnp.float32,
+                   page_tokens=4)
+    if not arena:
+        return BatchScheduler(model, params, cfg)
+    with monkeypatch.context() as m:    # the program non-GQA models run
+        m.setattr(paging.PageLayout, "kv_pages", property(lambda s: False))
+        return BatchScheduler(model, params, cfg)
+
+
+@pytest.mark.parametrize("attention", ["oracle", "kernel"])
+def test_paged_decode_streams_equal_the_arena_program(attention,
+                                                      monkeypatch):
+    import functools
+    from repro.models import layers
+    model, params = _tiny()
+    arena = _gqa_sched(model, params, arena=True, monkeypatch=monkeypatch)
+    if attention == "kernel":       # the Pallas kernel, interpreted
+        monkeypatch.setattr(layers.paged_ops, "paged_attention",
+                            functools.partial(
+                                layers.paged_ops.paged_attention,
+                                force_kernel=True, interpret=True))
+    paged = _gqa_sched(model, params)
+    assert paged.pool.paged and not arena.pool.paged
+    streams = []
+    for sched in (paged, arena):
+        for i, p in enumerate(GQA_PROMPTS):
+            sched.submit(Request(rid=i, prompt=list(p), max_new=6))
+        streams.append({r.rid: r.generated for r in sched.run()})
+        sched.pool.check_integrity()
+    assert streams[0] == streams[1]
+    assert all(len(t) == 6 for t in streams[0].values())
+
+
+def test_paged_decode_logits_and_cache_match_the_arena():
+    """One decode of the same pool, both ways: the logits agree, the new
+    token's K/V is the one the arena program writes into its row, and the
+    pool's paged program writes it into its page and nothing else."""
+    model, params = _tiny()
+    sched = _gqa_sched(model, params)
+    for i, p in enumerate(GQA_PROMPTS[:3]):
+        sched.submit(Request(rid=i, prompt=list(p), max_new=8))
+    while sched._prefills:
+        sched.step()
+    pool = sched.pool
+    rids = [s.rid for s in sched.slots]
+    mask = [True, False, True]                     # slot 1 sits this out
+    active = jnp.asarray(mask)
+    table = pool.table_array(rids)
+    tok = sched._next_tok[:, None]
+    arena = pool._assemble(pool.pool, pool.state, table)
+    want, want_caches = model.decode_step(params, {"tokens": tok}, arena)
+    got, got_caches = model.decode_step_paged(
+        params, {"tokens": tok}, pool._paged_view(pool.pool, pool.state),
+        table, active)
+    np.testing.assert_allclose(np.asarray(got)[[0, 2]],
+                               np.asarray(want)[[0, 2]], atol=1e-4,
+                               rtol=1e-4)
+    c_want = want_caches["stage0"]["layer0"]
+    c_got = got_caches["stage0"]["layer0"]
+    lens = [pool.tables[r].tokens for r in rids]
+    for slot in (0, 2):
+        for f in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(c_got[f][:, slot]),
+                np.asarray(c_want[f][:, slot, lens[slot]]), atol=1e-5,
+                rtol=1e-5)
+    before = [np.asarray(p) for p in pool.pool]
+    np.testing.assert_array_equal(np.asarray(c_got["len"]),
+                                  np.asarray(pool.state[0])
+                                  + np.asarray([1, 0, 1]))
+    sched._decode(params, tok, sched._rids, sched._pos,
+                  [r if a else None for r, a in zip(rids, mask)], mask)
+    pt = pool.page_tokens
+    for leaf, f in ((0, "k"), (1, "v")):
+        after = np.asarray(pool.pool[leaf])
+        want_pool = before[leaf].copy()
+        for slot in (0, 2):
+            n = lens[slot]
+            page = pool.tables[rids[slot]].pages[n // pt]
+            want_pool[page, n % pt] = np.asarray(c_got[f][:, slot])
+        np.testing.assert_allclose(after, want_pool, atol=1e-6, rtol=1e-6)
+    assert [pool.tables[r].tokens for r in rids] == \
+        [lens[0] + 1, lens[1], lens[2] + 1]
+
+
+def test_paged_decode_donates_the_pool_and_the_state_arena():
+    model, params = _tiny()
+    sched = _gqa_sched(model, params)
+    sched.submit(Request(rid=0, prompt=[1, 2, 3], max_new=4))
+    assert not sched._prefills               # one chunk: decoding already
+    old = list(sched.pool.pool) + list(sched.pool.state)
+    sched.step()
+    assert sched.decode_steps == 1
+    assert all(a.is_deleted() for a in old)
+
+
+def test_decode_span_reports_the_paged_path_and_its_live_pages(monkeypatch):
+    from repro.serve import engine
+    model, params = _tiny()
+    sched = _gqa_sched(model, params)
+    spans = Spans()
+    monkeypatch.setattr(engine, "TraceAnnotation", spans)
+    live = []
+    decode = sched._decode
+
+    def counted(params, tok, rids, pos, slot_rids, mask):
+        pool = sched.pool
+        live.append(sum(pool.pages_for(pool.tables[r].tokens)
+                        for r, a in zip(slot_rids, mask) if a))
+        return decode(params, tok, rids, pos, slot_rids, mask)
+
+    sched._decode = counted
+    for i, p in enumerate(GQA_PROMPTS):
+        sched.submit(Request(rid=i, prompt=list(p), max_new=6))
+    sched.run()
+    meta = spans.named("serve.decode")
+    assert len(meta) == len(live) == sched.decode_steps
+    assert all(m["paged"] == 1 for m in meta)
+    assert [m["kv_pages"] for m in meta] == live
+    assert max(live) > 3
+
+
+@pytest.mark.parametrize("arch", ["mamba2-1.3b", "deepseek-v3-671b"])
+def test_models_without_an_all_gqa_cache_keep_the_arena(arch, monkeypatch):
+    from repro.serve import engine
+    model, params = _tiny(arch)
+    cfg = ServeCfg(max_len=16, batch=2, cache_dtype=jnp.float32,
+                   page_tokens=4)
+    sched = BatchScheduler(model, params, cfg)
+    assert not sched.pool.paged
+    spans = Spans()
+    monkeypatch.setattr(engine, "TraceAnnotation", spans)
+    for i in range(3):
+        sched.submit(Request(rid=i, prompt=[i + 1] * (3 + i), max_new=3))
+    done = sched.run()
+    assert sorted(len(r.generated) for r in done) == [3, 3, 3]
+    meta = spans.named("serve.decode")
+    assert meta and all(m["paged"] == 0 for m in meta)
+    pps = sched.pool.layout.pages_per_slot
+    assert all(m["kv_pages"] == cfg.batch * pps for m in meta)
+
+
+@pytest.mark.parametrize("arch,backend,devices,paged", [
+    ("qwen2-72b", "cpu", 1, True),
+    ("qwen2-72b", "cpu", 8, True),      # the oracle partitions like jnp
+    ("qwen2-72b", "tpu", 1, True),
+    ("qwen2-72b", "tpu", 4, False),     # a Mosaic kernel spans one device
+    ("granite-34b", "cpu", 1, True),
+    ("granite-34b", "tpu", 1, False),   # one bf16 KV head: no page copy
+    ("nemotron-4-340b", "tpu", 1, False),   # head dim 192
+    ("mamba2-1.3b", "cpu", 1, False),
+])
+def test_decode_program_follows_layout_backend_and_mesh(arch, backend,
+                                                        devices, paged,
+                                                        monkeypatch):
+    """On the chip the paged decode is always the kernel: a layout the
+    kernel cannot copy, or a decode over a mesh, keeps the arena."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serve.paging import decodes_paged, probe_layout
+    model = build_model(get_config(arch))
+    layout = probe_layout(model, 64, 16, dtype=jnp.bfloat16)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert decodes_paged(layout, devices) == paged
