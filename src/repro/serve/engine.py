@@ -50,8 +50,17 @@ nest: ``serve.step`` (``step_num``) holds ``serve.admit``,
 paged decode ran, 0 for the arena program; ``kv_pages``: the KV pages
 its attention reads, from the host's books) and ``serve.sample``
 (token readback and the slot bookkeeping after it; ``rid`` after a
-prompt's last chunk); the pool's ``serve.pages`` sits inside a chunk or
-a decode.  ``serve.admit`` also runs inside ``submit()``.
+prompt's last chunk; ``reads``: device->host reads it made); the pool's
+``serve.pages`` (``uploads``: host->device transfers it made) sits
+inside a chunk or a decode.  ``serve.admit`` also runs inside
+``submit()``.
+
+Transfers: the scheduler keeps each slot's token, rid and position on
+the host, and every program gets its host-side arguments in one upload
+(``PagePool.put``); a step reads its tokens back in one read
+(``jax.device_get``).  ``h2d_uploads`` and ``d2h_reads`` count them: in
+steady state one upload per decode or chunk program, one read per
+decode step and one per finished prompt.
 """
 
 from __future__ import annotations
@@ -65,6 +74,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.serve import paging
@@ -268,9 +278,11 @@ class BatchScheduler:
         self._chunk = self.pool.bind_prefill_chunk(
             make_prefill_chunk_step(model)) if self._chunkable else None
         self._prefills: Dict[int, _Prefill] = {}   # slot -> in-progress
-        self._next_tok = jnp.zeros((cfg.batch,), jnp.int32)
-        self._rids = jnp.zeros((cfg.batch,), jnp.int32)
-        self._pos = jnp.zeros((cfg.batch,), jnp.int32)
+        # per slot, on the host: next input token, rid, position
+        self._next_tok = np.zeros((cfg.batch,), np.int32)
+        self._rids = np.zeros((cfg.batch,), np.int32)
+        self._pos = np.zeros((cfg.batch,), np.int32)
+        self.d2h_reads = 0           # explicit device->host reads
         self.completed: List[Request] = []
         self.shed: List[Request] = []
         self.decode_steps = 0
@@ -328,7 +340,7 @@ class BatchScheduler:
                     if not self.pool.has_room(first):
                         break
                     self.queue.popleft()
-                    self.pool.ensure(req.rid, first)
+                    self.pool.ensure(req.rid, first, i)
                     self._prefills[i] = _Prefill(req,
                                                  self.pool.fresh_state1())
                     self.slots[i] = req
@@ -389,9 +401,7 @@ class BatchScheduler:
     def _place(self, i: int, req: Request) -> None:
         """Wire a request into slot ``i``: next token and the (rid, pos)
         sampling coordinates (its pages are already in the pool)."""
-        self._next_tok = self._next_tok.at[i].set(req.generated[-1])
-        self._rids = self._rids.at[i].set(req.rid)
-        self._pos = self._pos.at[i].set(len(req.generated))
+        self._set_slot(i, req.generated[-1], req.rid, len(req.generated))
         self.slots[i] = req
         self._admit_seq.setdefault(req.rid, self._seq)
         self._seq += 1
@@ -418,17 +428,17 @@ class BatchScheduler:
                 self.pool.ensure(req.rid, valid_len)
             except OutOfPages:
                 return False
-            chunk = req.prompt[c * pt:(c + 1) * pt]
-            chunk = list(chunk) + [0] * (pt - len(chunk))   # pad to the page
             last_index = (len(req.prompt) - 1) - c * pt     # final-chunk only
             logits, pf.state = self._chunk(
-                self.params, req.rid, jnp.asarray(chunk, jnp.int32)[None, :],
+                self.params, req.rid, req.prompt[c * pt:(c + 1) * pt],
                 c, valid_len, max(0, min(last_index, pt - 1)), pf.state)
             pf.chunks_done += 1
             if pf.chunks_done < self._n_chunks(req):
                 return True
-            with TraceAnnotation("serve.sample", rid=req.rid):
+            with TraceAnnotation("serve.sample", rid=req.rid) as span:
+                reads = self.d2h_reads
                 self._finish_prefill(i, pf, logits)
+                span.set_metadata(reads=self.d2h_reads - reads)
             return True
 
     def _finish_prefill(self, i: int, pf: _Prefill, logits) -> None:
@@ -437,9 +447,9 @@ class BatchScheduler:
         and the slot flips to decoding (or frees, if that token ends the
         request)."""
         req = pf.req
-        rid1 = jnp.asarray([req.rid], jnp.int32)
-        tok = int(_pick_tokens(logits, self.cfg, rid1,
-                               jnp.zeros_like(rid1))[0])
+        rid1 = np.asarray([req.rid], np.int32)
+        tok = int(self._read(_pick_tokens(logits, self.cfg, rid1,
+                                          np.zeros_like(rid1)))[0])
         req.generated.append(tok)
         if req.t_first is None:
             req.t_first = time.time()
@@ -451,9 +461,20 @@ class BatchScheduler:
             self.pool.release(req.rid)
             self._admit_seq.pop(req.rid, None)
             return
-        self._next_tok = self._next_tok.at[i].set(tok)
-        self._rids = self._rids.at[i].set(req.rid)
-        self._pos = self._pos.at[i].set(1)
+        self._set_slot(i, tok, req.rid, 1)
+
+    def _set_slot(self, i: int, tok: int, rid: int, pos: int) -> None:
+        self._next_tok[i], self._rids[i], self._pos[i] = tok, rid, pos
+
+    @property
+    def h2d_uploads(self) -> int:
+        """Explicit host->device transfers made so far (the pool's)."""
+        return self.pool.h2d_uploads
+
+    def _read(self, x) -> np.ndarray:
+        """One explicit device->host read, counted in ``d2h_reads``."""
+        self.d2h_reads += 1
+        return jax.device_get(x)
 
     # -- preemption --------------------------------------------------------
 
@@ -538,21 +559,23 @@ class BatchScheduler:
                     mask[i] = True
                 slot_rids = [s.rid if s is not None and mask[j] else None
                              for j, s in enumerate(self.slots)]
-                nxt = self._decode(self.params, self._next_tok[:, None],
-                                   self._rids, self._pos, slot_rids, mask)
-                self._pos = self._pos + 1
-        with TraceAnnotation("serve.sample"):
-            self._next_tok = nxt
+                nxt = self._decode(self.params, self._next_tok, self._rids,
+                                   self._pos, slot_rids, mask)
+                self._pos += 1
+        with TraceAnnotation("serve.sample") as span:
+            reads = self.d2h_reads
+            self._next_tok[:] = self._read(nxt)
             self.decode_steps += 1
             for i in active:
                 req = self.slots[i]
-                req.generated.append(int(nxt[i]))
+                req.generated.append(int(self._next_tok[i]))
                 if req.done or (self.cfg.eos_id >= 0
                                 and req.generated[-1] == self.cfg.eos_id):
                     self.completed.append(req)
                     self.slots[i] = None
                     self.pool.release(req.rid)
                     self._admit_seq.pop(req.rid, None)
+            span.set_metadata(reads=self.d2h_reads - reads)
         return len(active) + prefilling
 
     def pending(self) -> bool:

@@ -49,6 +49,13 @@ Programs
 - **Prefill chunk**: a batch-1 arena gathered from the request's pages,
   its chunk's page scattered back.
 
+Each program takes its host-side arguments (page table, token ids,
+positions, page ids, mask, scalars) packed into ONE int32 array, sent
+with one explicit ``jax.device_put`` (``PagePool.put``, counted in
+``h2d_uploads``) and sliced apart inside the jit.  The page table is
+not rebuilt per call: ``slot_pages`` mirrors every slot's table on the
+host and changes where pages are granted, released, spliced or moved.
+
 Degenerate layout: ``page_tokens == max_len`` IS the old contiguous
 layout (one page per slot), so the pool serves both and the serve bench
 can compare them like-for-like.
@@ -65,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.kernels.paged_attention import kernel as paged_kernel
 
@@ -384,8 +392,18 @@ class PagePool:
                 shape = list(rest)
                 shape.insert(min(l.batch_axis, len(rest)), cfg.batch)
                 self.state.append(jnp.zeros(tuple(shape), l.dtype))
-        self._jit_decode: Dict[int, Callable] = {}
-        self._jit_chunk: Optional[Callable] = None
+        # each slot's page ids on the host, 0 (the zero page) past its
+        # pages; the programs' page tables are read from here
+        self.slot_pages = np.zeros((cfg.batch, pps), np.int32)
+        self._slot_of: Dict[int, int] = {}    # rid -> the slot it sits in
+        # uploads land replicated over a concrete mesh, else on the
+        # default device
+        self._placement = NamedSharding(mesh, PartitionSpec()) \
+            if mesh is not None and hasattr(mesh, "devices") else None
+        self.h2d_uploads = 0                  # explicit host->device puts
+        self._slot_ids: Optional[List[jax.Array]] = None
+        self._zero_state1: Optional[List[jax.Array]] = None
+        self._jit_write_state: Optional[Callable] = None
         self._jit_splice_row: Optional[Callable] = None
 
     # -- books -------------------------------------------------------------
@@ -423,24 +441,37 @@ class PagePool:
     def has_room(self, n_tokens: int) -> bool:
         return self.pages_free >= self.pages_for(n_tokens)
 
-    def ensure(self, rid: int, n_tokens: int) -> List[int]:
+    def ensure(self, rid: int, n_tokens: int,
+               slot: Optional[int] = None) -> List[int]:
         """Grow ``rid``'s table to cover ``n_tokens`` positions; returns
         the newly allocated page ids.  Raises ``OutOfPages`` (allocating
-        nothing) when the pool cannot back the growth."""
+        nothing) when the pool cannot back the growth.  ``slot``: the
+        slot ``rid`` takes, whose row of ``slot_pages`` then follows its
+        table until ``release``."""
         table = self.tables.setdefault(rid, PageTable())
         need = self.pages_for(n_tokens) - len(table.pages)
-        if need <= 0:
-            return []
         if need > len(self._free):
             raise OutOfPages(
                 f"rid {rid} needs {need} page(s), {len(self._free)} free "
                 f"of {self.num_pages}")
-        new = [self._free.pop() for _ in range(need)]
+        new = [self._free.pop() for _ in range(max(need, 0))]
         table.pages.extend(new)
+        n = len(table.pages)
+        if slot is not None:         # the slot's last holder leaves it
+            self._slot_of = {r: s for r, s in self._slot_of.items()
+                             if s != slot}
+            self._slot_of[rid] = slot
+            self.slot_pages[slot] = 0
+            self.slot_pages[slot, :n] = table.pages
+        elif new and rid in self._slot_of:
+            self.slot_pages[self._slot_of[rid], n - len(new):n] = new
         return new
 
     def release(self, rid: int) -> int:
         """Free every page ``rid`` holds; returns how many."""
+        slot = self._slot_of.pop(rid, None)
+        if slot is not None:
+            self.slot_pages[slot] = 0
         table = self.tables.pop(rid, None)
         if table is None:
             return 0
@@ -465,19 +496,22 @@ class PagePool:
         assert not (free & set(seen)), "page both free and allocated"
         assert len(free) + len(seen) == self.num_pages, \
             (len(free), len(seen), self.num_pages)
+        assert len(set(self._slot_of.values())) == len(self._slot_of), \
+            "two requests in one slot"
+        for slot, row in enumerate(self.slot_pages):
+            rid = next((r for r, s in self._slot_of.items() if s == slot),
+                       None)
+            pages = self.tables[rid].pages if rid is not None else []
+            assert list(row) == pages + [0] * (len(row) - len(pages)), \
+                (slot, rid, row)
 
-    # -- table materialization --------------------------------------------
+    # -- uploads -----------------------------------------------------------
 
-    def _table_row(self, rid: Optional[int]) -> List[int]:
-        pps = self.layout.pages_per_slot
-        if rid is None or rid not in self.tables:
-            return [0] * pps
-        pages = self.tables[rid].pages
-        return list(pages) + [0] * (pps - len(pages))
-
-    def table_array(self, slot_rids: Sequence[Optional[int]]) -> jax.Array:
-        return jnp.asarray([self._table_row(r) for r in slot_rids],
-                           jnp.int32)
+    def put(self, host: np.ndarray) -> jax.Array:
+        """One explicit host->device transfer (counted in
+        ``h2d_uploads``), replicated over the communicator's mesh."""
+        self.h2d_uploads += 1
+        return jax.device_put(host, self._placement)
 
     def decode_kv_pages(self, rids: Sequence[int]) -> int:
         """Pages the next decode's attention reads for the decoding
@@ -540,8 +574,9 @@ class PagePool:
 
     def bind_decode(self, decode_fn, paged_fn) -> Callable:
         """One jitted decode step for every slot.  Returns ``fn(params,
-        tok, rids, pos, slot_rids, active_mask)`` -> next tokens (and
-        commits pool/state internally).  Which program it runs is fixed
+        tok, rids, pos, slot_rids, active_mask)`` -> next tokens on the
+        device (and commits pool/state internally); ``tok``, ``rids`` and
+        ``pos`` are the slots' (B,) int32 host arrays.  Which program it runs is fixed
         by the probed layout (``self.paged``); both are named
         ``paged_decode`` (``jit_paged_decode`` in a profiler trace), and
         building and uploading their page arguments is the
@@ -557,12 +592,11 @@ class PagePool:
           active slot's touched page back.
 
         The returned function's ``program`` is the jitted program, whose
-        arguments are ``(params, pool, state, tok, rids, pos, table,
-        pids, idx, active)``: ``idx`` (B,) is the new token's page of its
-        slot (arena) or its offset in its page (paged)."""
+        arguments are ``(params, pool, state, packed)``: ``packed`` is
+        ``pack_decode``'s array, uploaded once per call."""
         if self.paged:
-            return self._bind_paged_decode(paged_fn)
-        return self._bind_arena_decode(decode_fn)
+            return self._decode_runner(self._paged_program(paged_fn))
+        return self._decode_runner(self._arena_program(decode_fn))
 
     def _paged_view(self, pool, state):
         """The cache tree with the pool's token leaves and the state
@@ -573,24 +607,58 @@ class PagePool:
                   for l in self.layout.leaves]
         return jax.tree_util.tree_unflatten(self.layout.treedef, leaves)
 
-    def _token_pages(self, slot_rids, active_mask, idle_pid: int):
-        """Host books -> (page id, position) of each slot's next token;
-        ``(idle_pid, 0)`` for a slot that does not decode."""
-        pids, positions = [], []
-        for r, a in zip(slot_rids, active_mask):
-            t = self.tables.get(r) if r is not None else None
-            if a and t is not None:
-                pids.append(t.page_of(t.tokens, self.page_tokens))
-                positions.append(t.tokens)
-            else:
-                pids.append(idle_pid)
-                positions.append(0)
-        return pids, positions
+    def pack_decode(self, tok, rids, pos, slot_rids, active_mask
+                    ) -> np.ndarray:
+        """Every host-side argument of one decode, as one ``(batch,
+        pages_per_slot + 6)`` int32 array.  A slot's row is its page
+        table (zeros where it does not decode), then its token, rid and
+        position, the page its new token goes to and that token's offset
+        in the page (paged) or the page's index in the table (arena), and
+        1 where it decodes.  A slot that does not decode writes to page 0
+        (arena, masked) or past the pool (paged, dropped)."""
+        pt, pps = self.page_tokens, self.layout.pages_per_slot
+        active = np.asarray(active_mask, bool)
+        out = np.zeros((self.cfg.batch, pps + 6), np.int32)
+        out[active, :pps] = self.slot_pages[active]
+        out[:, pps:pps + 3] = np.stack([tok, rids, pos], axis=1)
+        out[:, pps + 3] = self.num_pages + 1 if self.paged else 0
+        for s in np.flatnonzero(active):
+            t = self.tables[slot_rids[s]]
+            out[s, pps + 3] = t.page_of(t.tokens, pt)
+            out[s, pps + 4] = t.tokens % pt if self.paged else t.tokens // pt
+        out[:, pps + 5] = active
+        return out
 
-    def _bind_paged_decode(self, paged_fn) -> Callable:
+    def _unpack_decode(self, packed):
+        """Inside the program: ``pack_decode``'s array as (table, tok
+        (B, 1), rids, pos, pids, idx, active)."""
+        pps = self.layout.pages_per_slot
+        c = packed[:, pps:]
+        return (packed[:, :pps], c[:, :1], c[:, 1], c[:, 2], c[:, 3],
+                c[:, 4], c[:, 5] != 0)
+
+    def _decode_runner(self, program) -> Callable:
+        def run(params, tok, rids, pos, slot_rids, active_mask):
+            with TraceAnnotation("serve.pages") as span:
+                n0 = self.h2d_uploads
+                packed = self.put(self.pack_decode(tok, rids, pos,
+                                                   slot_rids, active_mask))
+                span.set_metadata(uploads=self.h2d_uploads - n0)
+            nxt, self.pool, self.state = program(params, self.pool,
+                                                 self.state, packed)
+            for r, a in zip(slot_rids, active_mask):
+                if a and r is not None:
+                    self.tables[r].tokens += 1
+            return nxt
+
+        run.program = program
+        return run
+
+    def _paged_program(self, paged_fn) -> Callable:
         @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def paged_decode(params, pool, state, tok, rids, pos, table, pids,
-                         offs, active):
+        def paged_decode(params, pool, state, packed):
+            table, tok, rids, pos, pids, offs, active = \
+                self._unpack_decode(packed)
             nxt, new = paged_fn(params, tok, self._paged_view(pool, state),
                                 table, active, rids, pos)
             toks, new_state = self._split(new)
@@ -601,31 +669,15 @@ class PagePool:
                 for p, t in zip(pool, toks)]
             return nxt, pool, new_state
 
-        def run(params, tok, rids, pos, slot_rids, active_mask):
-            with TraceAnnotation("serve.pages"):
-                pids, positions = self._token_pages(
-                    slot_rids, active_mask, self.num_pages + 1)
-                args = (self.table_array(slot_rids),
-                        jnp.asarray(pids, jnp.int32),
-                        jnp.asarray([n % self.page_tokens
-                                     for n in positions], jnp.int32),
-                        jnp.asarray(active_mask, jnp.bool_))
-            nxt, self.pool, self.state = paged_decode(
-                params, self.pool, self.state, tok, rids, pos, *args)
-            for r, a in zip(slot_rids, active_mask):
-                if a and r is not None:
-                    self.tables[r].tokens += 1
-            return nxt
+        return paged_decode
 
-        run.program = paged_decode
-        return run
-
-    def _bind_arena_decode(self, decode_fn) -> Callable:
+    def _arena_program(self, decode_fn) -> Callable:
         b = self.cfg.batch
 
         @jax.jit
-        def paged_decode(params, pool, state, tok, rids, pos, table, pids,
-                         ks, active):
+        def paged_decode(params, pool, state, packed):
+            table, tok, rids, pos, pids, ks, active = \
+                self._unpack_decode(packed)
             caches = self._assemble(pool, state, table)
             nxt, new_caches = decode_fn(params, tok, caches, rids, pos)
             tok_leaves, new_state = self._split(new_caches)
@@ -647,40 +699,40 @@ class PagePool:
                                            state[si]))
             return nxt, pool, out_state
 
-        def run(params, tok, rids, pos, slot_rids, active_mask):
-            with TraceAnnotation("serve.pages"):
-                pids, positions = self._token_pages(slot_rids, active_mask,
-                                                    0)
-                args = (self.table_array(slot_rids),
-                        jnp.asarray(pids, jnp.int32),
-                        jnp.asarray([n // self.page_tokens
-                                     for n in positions], jnp.int32),
-                        jnp.asarray(active_mask, jnp.bool_))
-            nxt, self.pool, self.state = paged_decode(
-                params, self.pool, self.state, tok, rids, pos, *args)
-            for r, a in zip(slot_rids, active_mask):
-                if a and r is not None:
-                    self.tables[r].tokens += 1
-            return nxt
+        return paged_decode
 
-        run.program = paged_decode
-
-        return run
+    def pack_chunk(self, rid: int, tokens: Sequence[int], chunk_idx: int,
+                   valid_len: int, last_index: int) -> np.ndarray:
+        """Every host-side argument of one prefill chunk, as one int32
+        vector: the chunk's query offset, ``valid_len``, ``last_index``,
+        its page id and index, the request's page table, then its
+        ``page_tokens`` token ids (zero-padded)."""
+        pt, pps = self.page_tokens, self.layout.pages_per_slot
+        out = np.zeros(5 + pps + pt, np.int32)
+        out[:5] = (chunk_idx * pt, valid_len, last_index,
+                   self.tables[rid].pages[chunk_idx], chunk_idx)
+        out[5:5 + pps] = self.slot_pages[self._slot_of[rid]]
+        out[5 + pps:5 + pps + len(tokens)] = tokens
+        return out
 
     def bind_prefill_chunk(self, chunk_fn) -> Callable:
         """One jitted prefill chunk over a batch-1 arena gathered from the
         request's pages: ``chunk_fn(params, tokens, caches, q_offset,
         valid_len, last_index)`` -> (logits, caches).  Writes the chunk's
         page back and returns (logits, state-leaves) for the caller to
-        carry between chunks.  The program is ``paged_prefill_chunk``;
-        its page arguments are built in a ``serve.pages`` span (``rid``)."""
+        carry between chunks.  The program is ``paged_prefill_chunk``,
+        whose host-side arguments are ``pack_chunk``'s vector, uploaded
+        in a ``serve.pages`` span (``rid``, ``uploads``)."""
+        pps = self.layout.pages_per_slot
 
         @jax.jit
-        def paged_prefill_chunk(params, pool, state1, tokens, table1,
-                                q_offset, valid_len, last_index, pid, k):
-            caches = self._assemble(pool, state1, table1)
-            logits, new_caches = chunk_fn(params, tokens, caches, q_offset,
-                                          valid_len, last_index)
+        def paged_prefill_chunk(params, pool, state1, packed):
+            q_offset, valid_len, last_index, pid, k = \
+                (packed[j] for j in range(5))
+            caches = self._assemble(pool, state1, packed[None, 5:5 + pps])
+            logits, new_caches = chunk_fn(params, packed[None, 5 + pps:],
+                                          caches, q_offset, valid_len,
+                                          last_index)
             tok_leaves, new_state = self._split(new_caches)
             pool = self._writeback_page(pool, tok_leaves, 0, pid, k,
                                         jnp.bool_(True))
@@ -688,31 +740,34 @@ class PagePool:
 
         def run(params, rid, tokens, chunk_idx, valid_len, last_index,
                 state1):
-            with TraceAnnotation("serve.pages", rid=rid):
-                t = self.tables[rid]
-                args = (self.table_array([rid]),
-                        jnp.int32(chunk_idx * self.page_tokens),
-                        jnp.int32(valid_len), jnp.int32(last_index),
-                        jnp.int32(t.pages[chunk_idx]), jnp.int32(chunk_idx))
+            with TraceAnnotation("serve.pages", rid=rid) as span:
+                n0 = self.h2d_uploads
+                packed = self.put(self.pack_chunk(rid, tokens, chunk_idx,
+                                                  valid_len, last_index))
+                span.set_metadata(uploads=self.h2d_uploads - n0)
             logits, self.pool, new_state = paged_prefill_chunk(
-                params, self.pool, state1, tokens, *args)
-            t.tokens = min(valid_len, (chunk_idx + 1) * self.page_tokens)
+                params, self.pool, state1, packed)
+            self.tables[rid].tokens = min(valid_len,
+                                          (chunk_idx + 1) * self.page_tokens)
             return logits, new_state
 
+        run.program = paged_prefill_chunk
         return run
 
     # -- state arena -------------------------------------------------------
 
     def fresh_state1(self) -> List[jax.Array]:
         """Zeroed batch-1 state leaves (a new request's non-positional
-        cache state, carried across prefill chunks)."""
-        out = []
-        for li in self.layout.state_leaf_ids:
-            l = self.layout.leaves[li]
-            shape = [1 if ax == l.batch_axis else s
-                     for ax, s in enumerate(l.shape)]
-            out.append(jnp.zeros(tuple(shape), l.dtype))
-        return out
+        cache state, carried across prefill chunks); made once and shared,
+        since no program donates them."""
+        if self._zero_state1 is None:
+            self._zero_state1 = []
+            for li in self.layout.state_leaf_ids:
+                l = self.layout.leaves[li]
+                shape = [1 if ax == l.batch_axis else s
+                         for ax, s in enumerate(l.shape)]
+                self._zero_state1.append(jnp.zeros(tuple(shape), l.dtype))
+        return list(self._zero_state1)
 
     def read_state(self, slot: int) -> List[jax.Array]:
         out = []
@@ -726,16 +781,31 @@ class PagePool:
         return out
 
     def write_state(self, slot: int, state1: Sequence[Any]) -> None:
-        new = []
-        for si, li in enumerate(self.layout.state_leaf_ids):
-            l = self.layout.leaves[li]
-            ax = min(l.batch_axis, self.state[si].ndim - 1)
-            one = jnp.asarray(state1[si]).astype(self.state[si].dtype)
-            if ax != l.batch_axis:
-                one = jnp.moveaxis(one, l.batch_axis, ax)
-            new.append(jax.lax.dynamic_update_slice_in_dim(
-                self.state[si], one, slot, axis=ax))
-        self.state = new
+        """Write batch-1 state leaves into slot ``slot`` of the arena, in
+        one program whose slot index is already on the device (the slot
+        indexes are uploaded once, with the first write)."""
+        if self._jit_write_state is None:
+            self._slot_ids = jax.device_put(
+                [np.int32(i) for i in range(self.cfg.batch)],
+                self._placement)
+            self.h2d_uploads += 1
+
+            @jax.jit
+            def write(state, state1, slot):
+                new = []
+                for si, li in enumerate(self.layout.state_leaf_ids):
+                    l = self.layout.leaves[li]
+                    ax = min(l.batch_axis, state[si].ndim - 1)
+                    one = state1[si].astype(state[si].dtype)
+                    if ax != l.batch_axis:
+                        one = jnp.moveaxis(one, l.batch_axis, ax)
+                    new.append(jax.lax.dynamic_update_slice_in_dim(
+                        state[si], one, slot, axis=ax))
+                return new
+
+            self._jit_write_state = write
+        self.state = self._jit_write_state(self.state, list(state1),
+                                           self._slot_ids[slot])
 
     # -- one-shot splice (models without chunked prefill) ------------------
 
@@ -745,7 +815,7 @@ class PagePool:
         into pool pages + slot state.  Pages are allocated here; the
         jitted scatter writes ``ceil(n_tokens/pt)`` pages (masked, so the
         trace is shared across token counts)."""
-        self.ensure(rid, n_tokens)
+        self.ensure(rid, n_tokens, slot)
         if self._jit_splice_row is None:
             pps = self.layout.pages_per_slot
             pt = self.page_tokens
@@ -772,9 +842,9 @@ class PagePool:
 
             self._jit_splice_row = splice
         t = self.tables[rid]
-        pids = jnp.asarray(self._table_row(rid), jnp.int32)
-        self.pool = self._jit_splice_row(self.pool, cache_b1, pids,
-                                         jnp.int32(len(t.pages)))
+        self.pool = self._jit_splice_row(self.pool, cache_b1,
+                                         self.slot_pages[slot].copy(),
+                                         np.int32(len(t.pages)))
         flat = jax.tree_util.tree_leaves(cache_b1)
         self.write_state(slot, [flat[i] for i in self.layout.state_leaf_ids])
         t.tokens = n_tokens
@@ -797,7 +867,7 @@ class PagePool:
         without side effects when the pool has no room."""
         if rid in self.tables and self.tables[rid].pages:
             raise ValueError(f"rid {rid} already holds pages")
-        self.ensure(rid, rc.tokens)
+        self.ensure(rid, rc.tokens, slot)
         t = self.tables[rid]
         idx = jnp.asarray(t.pages, jnp.int32)
         self.pool = [
@@ -838,6 +908,8 @@ class PagePool:
             for old, new in moves:
                 rid, j = owners[old]
                 self.tables[rid].pages[j] = new
+                if rid in self._slot_of:
+                    self.slot_pages[self._slot_of[rid], j] = new
         n_alloc = len(owners)
         self._free = list(range(self.num_pages, n_alloc, -1))
         return len(moves)

@@ -151,11 +151,10 @@ def test_pool_decode_program_compiles_on_its_mesh(one_chip, topo, chips,
     assert pool.paged == (chips == 1)
     run = pool.bind_decode(make_decode_step(model, cfg),
                            make_paged_decode_step(model, cfg))
-    ints = jnp.zeros((b,), jnp.int32)
+    ints = np.zeros((b,), np.int32)
     args = (jax.eval_shape(model.init, jax.random.PRNGKey(0)), pool.pool,
-            pool.state, ints[:, None], ints, ints,
-            pool.table_array([None] * b), ints, ints,
-            jnp.zeros((b,), jnp.bool_))
+            pool.state,
+            pool.pack_decode(ints, ints, ints, [None] * b, [False] * b))
     rep = NamedSharding(mesh, P())
     structs = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), args)

@@ -580,8 +580,8 @@ def test_paged_decode_logits_and_cache_match_the_arena():
     rids = [s.rid for s in sched.slots]
     mask = [True, False, True]                     # slot 1 sits this out
     active = jnp.asarray(mask)
-    table = pool.table_array(rids)
-    tok = sched._next_tok[:, None]
+    table = jnp.asarray(pool.slot_pages)
+    tok = jnp.asarray(sched._next_tok)[:, None]
     arena = pool._assemble(pool.pool, pool.state, table)
     want, want_caches = model.decode_step(params, {"tokens": tok}, arena)
     got, got_caches = model.decode_step_paged(
@@ -603,7 +603,7 @@ def test_paged_decode_logits_and_cache_match_the_arena():
     np.testing.assert_array_equal(np.asarray(c_got["len"]),
                                   np.asarray(pool.state[0])
                                   + np.asarray([1, 0, 1]))
-    sched._decode(params, tok, sched._rids, sched._pos,
+    sched._decode(params, sched._next_tok, sched._rids, sched._pos,
                   [r if a else None for r, a in zip(rids, mask)], mask)
     pt = pool.page_tokens
     for leaf, f in ((0, "k"), (1, "v")):
@@ -653,6 +653,44 @@ def test_decode_span_reports_the_paged_path_and_its_live_pages(monkeypatch):
     assert all(m["paged"] == 1 for m in meta)
     assert [m["kv_pages"] for m in meta] == live
     assert max(live) > 3
+
+
+@pytest.mark.parametrize("arena", [False, True], ids=["paged", "arena"])
+def test_steady_steps_upload_once_a_program_and_read_once_a_step(
+        arena, monkeypatch):
+    """In steady state each decode or chunk program gets its host-side
+    arguments in one explicit upload, and a step reads its tokens back in
+    one explicit read, plus one for a prompt's first token.  Under the
+    guard any implicit transfer raises."""
+    from repro.serve import engine
+    model, params = _tiny()
+    sched = _gqa_sched(model, params, arena=arena, monkeypatch=monkeypatch)
+    assert sched.pool.paged != arena
+    for rid, p in enumerate(([1, 2, 3], [4] * 5)):
+        sched.submit(Request(rid=rid, prompt=p, max_new=12))
+    while sched._prefills:
+        sched.step()
+    # four chunks: the first runs at submit, the last in the third step
+    late = Request(rid=2, prompt=list(range(1, 14)), max_new=2)
+    sched.submit(late)
+    assert list(sched._prefills) == [2]
+    spans = Spans()
+    monkeypatch.setattr(engine, "TraceAnnotation", spans)
+    ups, reads = sched.h2d_uploads, sched.d2h_reads
+    with jax.transfer_guard("disallow"):
+        for _ in range(4):
+            sched.step()
+    assert late.done and not sched._prefills
+    decodes = spans.named("serve.decode")
+    chunks = spans.named("serve.prefill_chunk")
+    assert [m["slots"] for m in decodes] == [2, 2, 3, 2]
+    assert len(chunks) == 3
+    assert sched.h2d_uploads - ups == len(decodes) + len(chunks)
+    assert sched.d2h_reads - reads == 4 + 1
+    samples = spans.named("serve.sample")
+    assert [m.get("rid") for m in samples] == [None, None, 2, None, None]
+    assert all(m["reads"] == 1 for m in samples)
+    sched.pool.check_integrity()
 
 
 @pytest.mark.parametrize("arch", ["mamba2-1.3b", "deepseek-v3-671b"])

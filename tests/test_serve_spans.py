@@ -146,3 +146,11 @@ def test_the_page_pool_programs_have_stable_names(runs):
     names = {e[2] for e in runs["events"]}
     assert {"PjitFunction(paged_decode)",
             "PjitFunction(paged_prefill_chunk)"} <= names
+
+
+def test_pages_spans_count_uploads_and_sample_spans_count_reads(runs):
+    # one upload per program's arguments, one read per token readback
+    pages = named(runs["spans"], "serve.pages")
+    assert pages and all(s[3]["uploads"] == 1 for s in pages)
+    samples = named(runs["spans"], "serve.sample")
+    assert samples and all(s[3]["reads"] == 1 for s in samples)
